@@ -20,14 +20,17 @@ TPU adaptation: the vector iterator maps to the 128-lane VPU axis, the
 next-inner to 8 sublanes; tiles snap to LANE/SUBLANE multiples; tile
 sizes are chosen so the working set — from the statement's *real* access
 groups (:func:`repro.core.cachemodel.stmt_access_groups`), times the
-double/triple-buffering factor — fits VMEM (~16 MiB usable).  This
-replaces the paper's externally-provided NPU tile sizes.
+double/triple-buffering factor — fits VMEM (~16 MiB usable).  The SSM
+kernels' blocks are not the statement's accesses (another layout, vreg
+padding of the state dim), so their plans are fitted to the kernels'
+real blocks instead (:func:`scan_block_bytes`).  This replaces the
+paper's externally-provided NPU tile sizes.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, replace
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from .config import tensor_style
 from .resilience import provenance as _provenance, schedule_with_ladder
@@ -79,21 +82,25 @@ def _iter_extents(scop: Scop, stmt: Statement) -> Dict[str, int]:
 
 
 def _fit_tiles(order: List[str], dims: Dict[str, int], vector_iter: str,
-               stmt: Statement, bytes_per_elem: int = 2,
-               n_buffers: int = 3,
-               fixed: Optional[Dict[str, int]] = None) -> Dict[str, int]:
+               stmt: Statement,
+               fixed: Optional[Dict[str, int]] = None,
+               block_bytes: Optional[Callable[[Dict[str, int]], int]] = None,
+               floor: Optional[Dict[str, int]] = None) -> Dict[str, int]:
     """Snap tiles to TPU-friendly sizes under a VMEM budget.
 
-    The working set always comes from the shared cache model
+    By default the working set comes from the shared cache model
     (:func:`repro.core.cachemodel.stmt_access_groups`): per-access tile
-    footprints from the statement's actual subscript strides, times
-    ``n_buffers`` for double/triple buffering — the same estimator that
-    sizes CPU cache tiles sizes VMEM tiles.  No heuristic fallback: the
-    statement's real access groups are required.
+    footprints from the statement's actual subscript strides, in bf16,
+    times 3 for triple buffering — the same estimator that sizes CPU
+    cache tiles sizes VMEM tiles.  A kernel whose blocks differ
+    from the statement's accesses (other operands, another layout, vreg
+    padding) passes ``block_bytes``: its real VMEM bytes for a tile,
+    which then replaces the estimate.
 
     ``fixed`` pins dims to a given tile (e.g. a VMEM-resident state dim
     that must stay whole); pinned dims are exempt from shrinking, so the
-    others shrink against the true footprint."""
+    others shrink against the true footprint.  ``floor`` gives the
+    smallest tile a dim may shrink to (default ``SUBLANE``)."""
     from .cachemodel import stmt_access_groups, working_set_bytes
 
     fixed = fixed or {}
@@ -107,26 +114,48 @@ def _fit_tiles(order: List[str], dims: Dict[str, int], vector_iter: str,
             tile[it] = max(min(tile[it], d), min(d, LANE))
         else:
             tile[it] = min(d, 128 if d >= 128 else d)
-    groups = stmt_access_groups(stmt, order)
+    if block_bytes is None:
+        groups = stmt_access_groups(stmt, order)
+
+        def block_bytes(tile):
+            sizes = [tile[i] for i in order]
+            return 3 * working_set_bytes(groups, sizes, 2)
+    low = {it: min(SUBLANE, dims[it]) for it in order}
+    low.update({it: min(v, dims[it]) for it, v in (floor or {}).items()})
 
     # shrink until the working set fits VMEM
-    def wset():
-        sizes = [tile[i] for i in order]
-        return n_buffers * working_set_bytes(groups, sizes, bytes_per_elem)
-
     shrink_order = [it for it in order if it != vector_iter and it not in fixed]
-    while wset() > VMEM_BYTES and any(tile[i] > SUBLANE for i in shrink_order):
+    while block_bytes(tile) > VMEM_BYTES and any(tile[i] > low[i]
+                                                 for i in shrink_order):
         for it in shrink_order:
-            if tile[it] > SUBLANE:
+            if tile[it] > low[it]:
                 tile[it] //= 2
                 break
     return tile
 
 
+def scan_block_bytes(tile: Dict[str, int], fused: bool) -> int:
+    """VMEM bytes the SSM kernels (:mod:`repro.kernels.mamba_scan`,
+    :mod:`repro.kernels.scan_gate` when ``fused``) hold for a (t, d, n)
+    tile: every operand counted as f32, each block's two minor dims
+    padded to the (8, 128) vreg tile, pipelined blocks double-buffered,
+    plus the state scratch.  Mirrors the kernels' BlockSpecs: a/b
+    (t, n, d), c (t, n, 1), per-step rows (t, d) — o, plus x and z when
+    fused — and, fused, the (1, d) skip and the h0/h_out (n, d) blocks."""
+    t, d, n = tile["t"], tile["d"], tile["n"]
+
+    def blk(rows: int, cols: int, lead: int = 1) -> int:
+        return (lead * -(-rows // SUBLANE) * SUBLANE
+                * -(-cols // LANE) * LANE * 4)
+
+    blocks = 2 * blk(n, d, t) + blk(n, 1, t) + blk(t, d)
+    if fused:
+        blocks += 2 * blk(t, d) + blk(1, d) + 2 * blk(n, d)
+    return 2 * blocks + blk(n, d)
+
+
 def lower_to_kernel_plan(tree: ScheduleTree, stmt_idx: Optional[int] = None,
-                         *, bytes_per_elem: int = 2, n_buffers: int = 3,
-                         fixed_tiles: Optional[Dict[str, int]] = None,
-                         sched=None) -> KernelPlan:
+                         *, sched=None) -> KernelPlan:
     """Map any scheduled SCoP's schedule tree to a :class:`KernelPlan`.
 
     * **grid order** — outer→inner point bands of the tree (tile/wave
@@ -174,9 +203,7 @@ def lower_to_kernel_plan(tree: ScheduleTree, stmt_idx: Optional[int] = None,
         vi = tree.vector_iter.get(stmt.index)
         vec = stmt.iters[vi] if vi is not None else order[-1]
     dims = _iter_extents(scop, stmt)
-    tile = _fit_tiles(order, dims, vec, stmt,
-                      bytes_per_elem=bytes_per_elem, n_buffers=n_buffers,
-                      fixed=fixed_tiles)
+    tile = _fit_tiles(order, dims, vec, stmt)
     prov = _provenance(sched) if sched is not None else None
     return KernelPlan(tuple(order), vec, tile, tuple(tree.sched_bands),
                       tree.pretty,
@@ -338,12 +365,24 @@ def plan_mamba_scan(seq: int, d_inner: int, state: int) -> KernelPlan:
     cfg = tensor_style()
     sched = schedule_with_ladder(s, cfg, cache=global_cache(),
                                  with_tree=True)
-    # kernel constraint: the hidden state (d_block × state) is VMEM-
-    # resident scratch across chunks — the state dim stays whole, pinned
-    # *inside* the fit so t/d shrink against the true footprint
-    return lower_to_kernel_plan(schedule_tree(sched), stmt_idx=0,
-                                bytes_per_elem=4, n_buffers=2,
-                                fixed_tiles={"n": state}, sched=sched)
+    plan = lower_to_kernel_plan(schedule_tree(sched), stmt_idx=0,
+                                sched=sched)
+    return _fit_scan_plan(s, plan, fused=False)
+
+
+def _fit_scan_plan(scop: Scop, plan: KernelPlan, fused: bool) -> KernelPlan:
+    """Re-fit a lowered SSM plan's tiles to the kernel's real blocks
+    (:func:`scan_block_bytes`).  The hidden state (state × d_block) is
+    VMEM-resident scratch across chunks, so the state dim stays whole,
+    pinned *inside* the fit so t/d shrink against the true footprint;
+    d rides the lanes, so its tile stays a whole lane width."""
+    stmt = scop.statements[0]
+    dims = _iter_extents(scop, stmt)
+    tile = _fit_tiles(list(plan.loop_order), dims, plan.vector_iter, stmt,
+                      fixed={"n": dims["n"]}, floor={"d": LANE},
+                      block_bytes=functools.partial(scan_block_bytes,
+                                                    fused=fused))
+    return replace(plan, tile=tile)
 
 
 def _scan_gate_scop(seq: int, d_inner: int, state: int) -> Scop:
@@ -392,10 +431,5 @@ def plan_scan_gate(seq: int, d_inner: int, state: int) -> KernelPlan:
         sched = schedule_with_ladder(scop, cfg, cache=global_cache(),
                                      with_tree=True)
         plan = lower_to_kernel_plan(schedule_tree(sched), stmt_idx=0,
-                                    bytes_per_elem=4, n_buffers=2,
-                                    fixed_tiles={"n": state}, sched=sched)
-    # kernel constraint (same as mamba_scan): the (d_block × state)
-    # hidden state is VMEM-resident across chunks — state stays whole.
-    tile = dict(plan.tile)
-    tile["n"] = state
-    return replace(plan, tile=tile)
+                                    sched=sched)
+    return _fit_scan_plan(scop, plan, fused=True)

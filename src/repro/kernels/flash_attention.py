@@ -24,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.akg import plan_attention
+from ._mode import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -72,7 +73,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     q_offset: Optional[jnp.ndarray] = None,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q, k, v: (bh, seq, d) — batch×heads flattened. GQA repetition is
     handled by the ops wrapper.  ``q_offset`` (scalar int32, traced)
     places the q rows at that sequence position for causal masking —
@@ -110,5 +111,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="flash_attention",
     )(off, q, k, v)

@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.akg import KernelPlan, plan_matmul
+from ._mode import resolve_interpret
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -46,7 +47,7 @@ def _pick(block: int, dim: int) -> int:
 
 def matmul(a: jnp.ndarray, b: jnp.ndarray,
            plan: Optional[KernelPlan] = None,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     """C[M,N] = A[M,K] @ B[K,N] with PolyTOPS-planned tiling."""
     m, k = a.shape
     k2, n = b.shape
@@ -67,5 +68,6 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="polytops_matmul",
     )(a, b)

@@ -1,13 +1,15 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only (the
-kernels target TPU; interpret mode executes the kernel bodies in Python
-for correctness validation). On TPU set REPRO_PALLAS_COMPILE=1.
+``interpret=None`` (the default) lets the backend choose: Mosaic
+compiles the kernels on a TPU, and the Pallas interpreter runs the
+kernel bodies on the CPU, for correctness checks
+(:func:`repro.kernels._mode.resolve_interpret`).  Any other backend is
+an error.  Pass ``True`` or ``False`` to force a mode.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,17 +19,15 @@ from . import mamba_scan as _ms
 from . import matmul_polytops as _mm
 from . import scan_gate as _sg
 
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
 
 @partial(jax.jit, static_argnames=("interpret",))
-def matmul(a, b, interpret: bool = INTERPRET):
+def matmul(a, b, interpret: Optional[bool] = None):
     return _mm.matmul(a, b, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, q_offset=None,
-                    interpret: bool = INTERPRET):
+                    interpret: Optional[bool] = None):
     """q: (b, s, h, d); k/v: (b, s, hkv, d) — GQA repeats kv heads.
     ``q_offset`` (scalar int32) positions the q chunk for causal
     masking against a longer kv prefix (chunked prefill)."""
@@ -46,13 +46,13 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=None,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def selective_scan(a_bar, b_bar, c, interpret: bool = INTERPRET):
+def selective_scan(a_bar, b_bar, c, interpret: Optional[bool] = None):
     return _ms.selective_scan(a_bar, b_bar, c, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def scan_gate(a_bar, b_bar, c, x_skip, d_skip, z, h0=None,
-              interpret: bool = INTERPRET):
+              interpret: Optional[bool] = None):
     """Fused selective-scan + skip + SiLU gate with state carry.
     Returns (o (b, s, di), h_last (b, di, st))."""
     return _sg.scan_gate(a_bar, b_bar, c, x_skip, d_skip, z, h0=h0,

@@ -6,11 +6,13 @@ One kernel computes what the jnp path spreads over four ops:
     y_t = h_t · c_t + x_t ⊙ d_skip                 (contraction + skip)
     o_t = y_t ⊙ silu(z_t)                          (gate)
 
-with the hidden state (d_block × state) VMEM-resident across sequence
+with the hidden state (state × d_block) VMEM-resident across sequence
 chunks and an explicit initial state ``h0`` — the carry that lets a
 serving engine process a prompt in chunks (continuous batching) without
 ever materializing the (b, s, d, n) hidden-state tensor in HBM between
-ops.  The final state is returned for the next chunk.
+ops.  The final state is returned for the next chunk.  The recurrence
+and the TPU layout (d on lanes, state on sublanes, aligned row groups)
+are shared with :mod:`.mamba_scan`.
 
 Block geometry comes from the scheduler: ``repro.core.akg.plan_scan_gate``
 builds the fused SCoP (recurrence + gate statement in one t/d nest),
@@ -29,6 +31,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mode import resolve_interpret
+from .mamba_scan import GROUP, block_geometry, scan_chunk, to_kernel_layout
+
 
 def _kernel(a_ref, b_ref, c_ref, x_ref, dk_ref, z_ref, h0_ref,
             o_ref, hout_ref, h_ref, *, chunk: int, n_chunks: int):
@@ -36,21 +41,15 @@ def _kernel(a_ref, b_ref, c_ref, x_ref, dk_ref, z_ref, h0_ref,
     def _init():
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    dk = dk_ref[0].astype(jnp.float32)               # (bd,)
+    dk = dk_ref[...].astype(jnp.float32)                 # (1, bd)
 
-    def step(t, h):
-        a_t = a_ref[0, t].astype(jnp.float32)        # (bd, st)
-        b_t = b_ref[0, t].astype(jnp.float32)        # (bd, st)
-        c_t = c_ref[0, t].astype(jnp.float32)        # (st,)
-        x_t = x_ref[0, t].astype(jnp.float32)        # (bd,)
-        z_t = z_ref[0, t].astype(jnp.float32)        # (bd,)
-        h = a_t * h + b_t
-        y = h @ c_t + x_t * dk
-        o_ref[0, t] = (y * (z_t * jax.nn.sigmoid(z_t))).astype(o_ref.dtype)
-        return h
+    def emit(t0, y):
+        x = x_ref[0, pl.ds(t0, GROUP), :].astype(jnp.float32)
+        z = z_ref[0, pl.ds(t0, GROUP), :].astype(jnp.float32)
+        o = (y + x * dk) * (z * jax.nn.sigmoid(z))
+        o_ref[0, pl.ds(t0, GROUP), :] = o.astype(o_ref.dtype)
 
-    h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
-    h_ref[...] = h
+    h_ref[...] = scan_chunk(a_ref, b_ref, c_ref, h_ref[...], chunk, emit)
 
     @pl.when(pl.program_id(2) == n_chunks - 1)
     def _store_state():
@@ -61,7 +60,8 @@ def scan_gate(a_bar: jnp.ndarray, b_bar: jnp.ndarray, c: jnp.ndarray,
               x_skip: jnp.ndarray, d_skip: jnp.ndarray, z: jnp.ndarray,
               h0: Optional[jnp.ndarray] = None,
               d_block: Optional[int] = None, chunk: Optional[int] = None,
-              interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              interpret: Optional[bool] = None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """a_bar, b_bar: (b, s, di, st); c: (b, s, st); x_skip, z: (b, s, di);
     d_skip: (di,); h0: (b, di, st) f32 or None (zeros).
     Returns (o (b, s, di), h_last (b, di, st) f32)."""
@@ -71,38 +71,36 @@ def scan_gate(a_bar: jnp.ndarray, b_bar: jnp.ndarray, c: jnp.ndarray,
         plan = plan_scan_gate(seq, di, st)
         d_block = d_block if d_block is not None else plan.tile["d"]
         chunk = chunk if chunk is not None else plan.tile["t"]
-    d_block = min(d_block, di)
-    while di % d_block:
-        d_block //= 2
-    chunk = min(chunk, seq)
-    while seq % chunk:
-        chunk //= 2
-    n_chunks = seq // chunk
+    seq_p, d_block, chunk = block_geometry(seq, di, d_block, chunk)
+    a, b, c4 = to_kernel_layout(a_bar, b_bar, c, seq_p - seq)
+    pad = ((0, 0), (0, seq_p - seq), (0, 0))
+    x_skip, z = jnp.pad(x_skip, pad), jnp.pad(z, pad)
     if h0 is None:
         h0 = jnp.zeros((bsz, di, st), jnp.float32)
-    dk2 = d_skip.reshape(1, di)
+    h0 = jnp.swapaxes(h0.astype(jnp.float32), 1, 2)          # (b, st, di)
+    n_chunks = seq_p // chunk
     grid = (bsz, di // d_block, n_chunks)
+    row = pl.BlockSpec((1, chunk, d_block), lambda i, d, t: (i, t, d))
+    state = pl.BlockSpec((1, st, d_block), lambda i, d, t: (i, 0, d))
     out, h_last = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, d_block, st), lambda b, dblk, t: (b, t, dblk, 0)),
-            pl.BlockSpec((1, chunk, d_block, st), lambda b, dblk, t: (b, t, dblk, 0)),
-            pl.BlockSpec((1, chunk, st), lambda b, dblk, t: (b, t, 0)),
-            pl.BlockSpec((1, chunk, d_block), lambda b, dblk, t: (b, t, dblk)),
-            pl.BlockSpec((1, d_block), lambda b, dblk, t: (0, dblk)),
-            pl.BlockSpec((1, chunk, d_block), lambda b, dblk, t: (b, t, dblk)),
-            pl.BlockSpec((1, d_block, st), lambda b, dblk, t: (b, dblk, 0)),
+            pl.BlockSpec((1, chunk, st, d_block), lambda i, d, t: (i, t, 0, d)),
+            pl.BlockSpec((1, chunk, st, d_block), lambda i, d, t: (i, t, 0, d)),
+            pl.BlockSpec((1, chunk, st, 1), lambda i, d, t: (i, t, 0, 0)),
+            row,
+            pl.BlockSpec((1, d_block), lambda i, d, t: (0, d)),
+            row,
+            state,
         ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, d_block), lambda b, dblk, t: (b, t, dblk)),
-            pl.BlockSpec((1, d_block, st), lambda b, dblk, t: (b, dblk, 0)),
-        ],
+        out_specs=[row, state],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, seq, di), x_skip.dtype),
-            jax.ShapeDtypeStruct((bsz, di, st), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, seq_p, di), x_skip.dtype),
+            jax.ShapeDtypeStruct((bsz, st, di), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((d_block, st), jnp.float32)],
-        interpret=interpret,
-    )(a_bar, b_bar, c, x_skip, dk2, z, h0.astype(jnp.float32))
-    return out, h_last
+        scratch_shapes=[pltpu.VMEM((st, d_block), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+        name="scan_gate",
+    )(a, b, c4, x_skip, d_skip.reshape(1, di), z, h0)
+    return out[:, :seq], jnp.swapaxes(h_last, 1, 2)

@@ -15,7 +15,7 @@ from __future__ import annotations
 from typing import Any, Dict, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.registry import ArchConfig
 
@@ -23,7 +23,10 @@ from ..configs.registry import ArchConfig
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the layers annotate activations with
+    # with_sharding_constraint, which Explicit axes (make_mesh's default
+    # since JAX 0.8) refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -2,8 +2,11 @@
 kernels.
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite_3_2b \
-        --batch 4 --prompt-len 32 --gen 16 [--engine continuous] \
-        [--pallas] [--smoke]
+        [--batch 4 --prompt-len 256 --gen 32 --chunk 256 --max-len N] \
+        [--seed 0] [--engine continuous] [--pallas] [--smoke]
+
+Without ``--smoke`` the arch is served at its published widths, with
+random weights made from ``--seed``.
 
 Two engines share the model's step functions:
 
@@ -155,11 +158,11 @@ class ContinuousEngine:
 
         self.cache = T.init_cache(cfg, batch, max_len)
         # device-resident decode state: (tokens (b,1), lengths (b,),
-        # out_buf (b, max_new), out_pos (b,))
-        self.dev = (jnp.zeros((batch, 1), jnp.int32),
-                    jnp.zeros((batch,), jnp.int32),
-                    jnp.zeros((batch, max_new), jnp.int32),
-                    jnp.zeros((batch,), jnp.int32))
+        # out_buf (b, max_new), out_pos (b,), finite () — False once any
+        # tick produced a NaN or infinite logit)
+        self.dev = self._fresh_dev(max_new)
+        # last-position logits of the newest prefill chunk (device array)
+        self.prefill_logits = None
         self.lengths = [0] * batch          # host mirror of dev[1]
         self.gen_count = [0] * batch        # host mirror of dev[3]
         self.state = [FREE] * batch
@@ -172,8 +175,9 @@ class ContinuousEngine:
         self.ticks_overlap = 0
 
         def _decode_tick(p, c, dev, act, kv):
-            toks, lens, buf, pos = dev
+            toks, lens, buf, pos, ok = dev
             logits, c = T.serve_decode_step(p, cfg, toks, c, lens, act, kv)
+            ok = ok & jnp.all(jnp.isfinite(logits) | ~act[:, None])
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)        # (b,)
             toks = jnp.where(act[:, None], nxt[:, None], toks)
             lens = lens + act
@@ -181,13 +185,14 @@ class ContinuousEngine:
                            jax.lax.dynamic_update_slice(b, t[None], (i,)))
             buf = jnp.where(act[:, None], upd(buf, nxt, pos), buf)
             pos = pos + act
-            return c, (toks, lens, buf, pos), nxt
+            return c, (toks, lens, buf, pos, ok), nxt
 
         def _chunk_tick(p, toks, c, dev, off, slot, last, kv):
             sub = T.cache_slot_view(c, slot)
             logits, sub = T.chunk_step(p, cfg, toks, sub, off, kv)
             c = T.cache_slot_write(c, sub, slot)
-            t, lens, buf, pos = dev
+            t, lens, buf, pos, ok = dev
+            ok = ok & jnp.all(jnp.isfinite(logits))
             sl = jnp.arange(t.shape[0]) == slot
             end = off + toks.shape[1]
             lens = jnp.where(sl, end, lens)
@@ -199,7 +204,7 @@ class ContinuousEngine:
                             & (jnp.arange(buf.shape[1]) == 0)[None, :],
                             ctok, buf)
             pos = jnp.where(fin, 1, pos)
-            return c, (t, lens, buf, pos)
+            return c, (t, lens, buf, pos, ok), logits[0, -1]
 
         def _mixed_tick(p, toks, c, dev, act, off, slot, last, kv_d, kv_p):
             # overlap tick: decode every active slot AND land one prefill
@@ -207,8 +212,9 @@ class ContinuousEngine:
             # write into the prefilling slot (row = that slot's current
             # length) is overwritten by the chunk that follows.
             c, dev, nxt = _decode_tick(p, c, dev, act, kv_d)
-            c, dev = _chunk_tick(p, toks, c, dev, off, slot, last, kv_p)
-            return c, dev, nxt
+            c, dev, last_logits = _chunk_tick(p, toks, c, dev, off, slot,
+                                              last, kv_p)
+            return c, dev, nxt, last_logits
 
         def _decode_k(p, c, dev, act, kv, k):
             # k decode steps fused into one dispatch (steady state: no
@@ -231,12 +237,33 @@ class ContinuousEngine:
                               donate_argnums=(2, 3))
 
         def _admit(c, dev, s):
-            t, lens, buf, pos = dev
+            t, lens, buf, pos, ok = dev
             sl = jnp.arange(t.shape[0]) == s
             return (T.zero_cache_slot(c, s),
-                    (t, jnp.where(sl, 0, lens), buf, jnp.where(sl, 0, pos)))
+                    (t, jnp.where(sl, 0, lens), buf, jnp.where(sl, 0, pos),
+                     ok))
 
         self._admit = jax.jit(_admit, donate_argnums=(0, 1))
+
+    def _fresh_dev(self, max_new: int):
+        b = self.batch
+        return (jnp.zeros((b, 1), jnp.int32), jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b, max_new), jnp.int32),
+                jnp.zeros((b,), jnp.int32), jnp.ones((), bool))
+
+    def logits_finite(self) -> bool:
+        """True while no tick since the last reset produced a NaN or
+        infinite logit (one host read)."""
+        return bool(jax.device_get(self.dev[4]))
+
+    def lower_chunk(self, n: int, kv: int):
+        """Lower the prefill chunk tick for an ``n``-token chunk against
+        a ``kv``-row prefix, in this engine's kernel mode — the program
+        :meth:`tick` runs for such a chunk."""
+        pallas_mode.configure(**self._mode_kw)
+        return self._chunk.lower(
+            self.params, jnp.zeros((1, n), jnp.int32), self.cache, self.dev,
+            jnp.int32(0), jnp.int32(0), jnp.asarray(True), kv)
 
     # -- admission -------------------------------------------------------
     def submit(self, req: Request):
@@ -320,7 +347,7 @@ class ContinuousEngine:
             last = off + c == req.prompt.shape[1]
 
         if decoding and ci is not None:
-            self.cache, self.dev, nxt_dev = self._mixed(
+            self.cache, self.dev, nxt_dev, self.prefill_logits = self._mixed(
                 self.params, toks, self.cache, self.dev, self._active,
                 jnp.int32(off), jnp.int32(ci), jnp.asarray(last),
                 kv_d, kv_p)
@@ -332,7 +359,7 @@ class ContinuousEngine:
                 self.params, self.cache, self.dev, self._active, kv_d)
             self.ticks_decode += 1
         else:
-            self.cache, self.dev = self._chunk(
+            self.cache, self.dev, self.prefill_logits = self._chunk(
                 self.params, toks, self.cache, self.dev, jnp.int32(off),
                 jnp.int32(ci), jnp.asarray(last), kv_p)
             self.ticks_prefill += 1
@@ -396,10 +423,8 @@ class ContinuousEngine:
         """Back to the post-init state, keeping compiled tick functions."""
         b = self.batch
         self.cache = jax.tree.map(jnp.zeros_like, self.cache)
-        self.dev = (jnp.zeros((b, 1), jnp.int32),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.zeros_like(self.dev[2]),
-                    jnp.zeros((b,), jnp.int32))
+        self.dev = self._fresh_dev(self.dev[2].shape[1])
+        self.prefill_logits = None
         self.lengths = [0] * b
         self.gen_count = [0] * b
         self.state = [FREE] * b
@@ -416,21 +441,25 @@ class ContinuousEngine:
         return self.ticks_overlap / busy
 
 
-def warm_kernel_plans(cfg, max_len: int, chunk: int = 16) -> None:
+def warm_kernel_plans(cfg, max_len: int, chunk: int = 16) -> int:
     """Plan the serving kernels up front, through a schedd daemon when
     ``$POLYTOPS_SCHEDD_SOCK`` names one (so N serving processes
     amortize one scheduler) and in-process otherwise — ``akg``'s remote
-    hook makes the same call total either way."""
+    hook makes the same call total either way.  Plans the shapes a
+    prefill chunk of ``chunk`` rows runs; returns how many plans came
+    back degraded (lowered from a fallback schedule)."""
     from ..core import akg
     from ..core.schedclient import maybe_client
 
     client = maybe_client()
-    plans = [akg.plan_matmul(cfg.d_model, cfg.d_ff, cfg.d_model),
-             akg.plan_attention(max_len, max_len, cfg.hd),
-             akg.plan_attention(max(chunk, 8), max_len, cfg.hd)]
+    rows = max(chunk, 8)
+    plans = [akg.plan_attention(max_len, max_len, cfg.hd),
+             akg.plan_attention(rows, max_len, cfg.hd)]
+    if cfg.d_ff:
+        plans += [akg.plan_matmul(rows, cfg.d_ff, cfg.d_model),
+                  akg.plan_matmul(rows, cfg.d_model, cfg.d_ff)]
     if cfg.d_inner and cfg.ssm_state:
-        plans.append(akg.plan_scan_gate(max(chunk, 8), cfg.d_inner,
-                                        cfg.ssm_state))
+        plans.append(akg.plan_scan_gate(rows, cfg.d_inner, cfg.ssm_state))
     degraded = sum(1 for p in plans if p.degraded)
     if client is not None:
         st = client.stats.as_dict()
@@ -440,28 +469,45 @@ def warm_kernel_plans(cfg, max_len: int, chunk: int = 16) -> None:
         via = "in-process"
     print(f"serve: {len(plans)} kernel plans warmed {via}"
           + (f", {degraded} degraded" if degraded else ""))
+    return degraded
 
 
-def main():
+def init_params(cfg, seed: int):
+    """Random parameters made on the device by one jitted program (a
+    full-width model is never built leaf by leaf from the host)."""
+    return jax.jit(T.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def main(argv=None) -> int:
+    from .compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite_3_2b")
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--gen", type=int, default=12)
+    ap.add_argument("--prompt-len", type=int, default=256)
+    ap.add_argument("--gen", type=int, default=32)
+    ap.add_argument("--max-len", type=int, default=0,
+                    help="KV rows per slot (default: prompt-len + gen + 1)")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--engine", choices=("alternating", "continuous"),
                     default="continuous")
-    ap.add_argument("--chunk", type=int, default=16)
+    ap.add_argument("--chunk", type=int, default=256)
     ap.add_argument("--pallas", action="store_true")
-    ap.add_argument("--smoke", action="store_true", default=True)
-    args = ap.parse_args()
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced smoke config")
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
-    max_len = args.prompt_len + args.gen + 1
-    warm_kernel_plans(cfg, max_len, args.chunk)
-    key = jax.random.PRNGKey(0)
-    params = T.init_params(key, cfg)
+    max_len = args.max_len or args.prompt_len + args.gen + 1
+    if warm_kernel_plans(cfg, max_len, args.chunk):
+        print("serve: degraded kernel plans; refusing to serve")
+        return 1
+    key = jax.random.PRNGKey(args.seed)
+    params = init_params(cfg, args.seed)
     prompts = [jax.random.randint(jax.random.fold_in(key, i),
                                   (1, args.prompt_len), 2, cfg.vocab)
                for i in range(args.batch)]
@@ -473,6 +519,7 @@ def main():
         for _ in range(args.gen - 1):
             eng.step()
         reqs = [r for r in eng.slots if r is not None]
+        jax.block_until_ready(eng.cache)
     else:
         ceng = ContinuousEngine(cfg, params, args.batch, max_len,
                                 chunk=args.chunk, use_pallas=args.pallas,
@@ -481,15 +528,19 @@ def main():
         for r in reqs:
             ceng.submit(r)
         ceng.run()
+        jax.block_until_ready(ceng.cache)
         print(f"overlap ratio: {ceng.overlap_ratio():.2f}, "
               f"page={ceng.page}")
     dt = time.time() - t0
     ntok = sum(len(r.generated) for r in reqs)
-    print(f"{len(reqs)} seqs, {ntok} tokens in {dt:.2f}s "
-          f"({ntok/max(dt,1e-9):.1f} tok/s, CPU smoke, {args.engine})")
+    dev = jax.devices()
+    print(f"{len(reqs)} seqs, {ntok} tokens in {dt:.2f}s host wall time, "
+          f"compiles included ({cfg.name}, {args.engine}, "
+          f"{dev[0].platform} {dev[0].device_kind} x{len(dev)})")
     for req in reqs:
         print(f"req{req.rid}: {req.generated[:10]}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

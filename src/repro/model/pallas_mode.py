@@ -10,11 +10,14 @@ same inputs, so a jit retrace picks the mode up and numerical parity
 against the jnp path is a plain ``allclose`` (asserted by
 ``tests/test_serve.py`` and the serving engine's startup parity check).
 
-Thresholds exist because this container runs the kernels in interpret
-mode (CPU): the flash-attention kernel beats the materialized-softmax
-jnp path from ~64 query rows up, while a 32-row matmul is cheaper as
-one XLA dot.  On a real TPU (``REPRO_PALLAS_COMPILE=1``) the thresholds
-drop to the kernels' minimum tile sizes.
+The thresholds are the same on every backend.  They were tuned on the
+CPU, where the kernels run in the Pallas interpreter: the
+flash-attention kernel beat the materialized-softmax jnp path from ~64
+query rows up, while a 32-row matmul was cheaper as one XLA dot.  On a
+TPU the kernels compile with Mosaic (:mod:`repro.kernels._mode`) under
+these same thresholds, so a serving chunk must reach ``min_attn_q`` /
+``min_matmul_rows`` rows for a kernel to run; no threshold has been
+tuned on the chip yet.
 
 Follows the module-level-config idiom of ``transformer.UNROLL`` /
 ``attention.ATTN_CHUNK``: the launcher installs the mode once, layers

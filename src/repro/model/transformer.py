@@ -119,13 +119,13 @@ def _init_stack(key, cfg: ArchConfig, role: str, dtype) -> Dict:
     period = pattern_period(cfg, role)
     n = len(specs)
     repeats, tail_n = divmod(n, period)
-    # stacked params per slot in the period
+    # stacked params per slot in the period (vmapped over the layer keys:
+    # one program per slot, not one per layer, when init is jitted)
     slots = []
     for s in range(period):
         keys = jax.random.split(jax.random.fold_in(key, s), max(repeats, 1))
-        layers = [_init_layer(keys[r], cfg, specs[s], dtype) for r in range(repeats)]
-        slots.append(jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
-                     if repeats > 0 else None)
+        slots.append(jax.vmap(lambda k: _init_layer(k, cfg, specs[s], dtype))(
+            keys) if repeats > 0 else None)
     tail = [
         _init_layer(jax.random.fold_in(key, 10_000 + i), cfg,
                     specs[repeats * period + i], dtype)
