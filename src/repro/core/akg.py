@@ -236,12 +236,6 @@ PLAN_MEMO_DEFAULTS: Dict[str, int] = {
     "matmul": 64, "attention": 64, "mamba_scan": 16, "scan_gate": 16,
 }
 
-#: per-planner :class:`~repro.core.schedcache.CacheStats` — hits/misses/
-#: evicted of the in-process plan memos, inspectable via
-#: :func:`plan_memo_stats` (serve/bench surface them next to the
-#: schedule-cache stats).
-_PLAN_MEMO_STATS: Dict[str, "object"] = {}
-
 
 def plan_memo_size(name: str) -> int:
     """Resolved memo capacity for planner ``name`` (env-overridable)."""
@@ -256,11 +250,6 @@ def plan_memo_size(name: str) -> int:
     return PLAN_MEMO_DEFAULTS.get(name, 16)
 
 
-def plan_memo_stats() -> Dict[str, Dict[str, object]]:
-    """``{planner: CacheStats.as_dict()}`` for every registered memo."""
-    return {name: st.as_dict() for name, st in _PLAN_MEMO_STATS.items()}
-
-
 def _plan_memo(name: str):
     """Like ``functools.lru_cache`` but degraded plans are returned
     without being pinned: a plan lowered from a fault- or deadline-
@@ -270,13 +259,7 @@ def _plan_memo(name: str):
     degraded schedules are never published).
 
     Capacity is resolved per call via :func:`plan_memo_size`, so a
-    serving process can widen a thrashing memo with one env var; every
-    hit/miss/eviction is counted in the planner's
-    :class:`~repro.core.schedcache.CacheStats`."""
-    from .schedcache import CacheStats
-
-    stats = _PLAN_MEMO_STATS.setdefault(name, CacheStats())
-
+    serving process can widen a thrashing memo with one env var."""
     def deco(fn):
         memo: Dict[tuple, KernelPlan] = {}
 
@@ -284,19 +267,15 @@ def _plan_memo(name: str):
         def wrapper(*args):
             hit = memo.get(args)
             if hit is not None:
-                stats.hits += 1
                 return hit
-            stats.misses += 1
             plan = fn(*args)
             if not plan.degraded:
                 while len(memo) >= plan_memo_size(name):  # FIFO, as lru
                     memo.pop(next(iter(memo)))
-                    stats.evicted += 1
                 memo[args] = plan
             return plan
 
         wrapper.cache_clear = memo.clear
-        wrapper.stats = stats
         return wrapper
     return deco
 

@@ -48,6 +48,7 @@ class Request:
     done: bool = False
     max_new: int = 0               # 0 = engine default
     t_submit: float = 0.0
+    t_admit: float = 0.0           # taken into a slot
     t_first: float = 0.0           # first generated token (prefill done)
     token_times: List[float] = field(default_factory=list)
 
@@ -64,10 +65,11 @@ def _merge_slot(cache: Dict, pre: Dict, slot) -> Dict:
             return jax.lax.dynamic_update_slice(dst, src.astype(dst.dtype),
                                                 starts)
         return go
-    return {"slots": [jax.tree.map(wr(1), c, sc)
-                      for c, sc in zip(cache["slots"], pre["slots"])],
-            "tail": [jax.tree.map(wr(0), c, sc)
-                     for c, sc in zip(cache["tail"], pre["tail"])]}
+    with jax.named_scope("kv_cache"):
+        return {"slots": [jax.tree.map(wr(1), c, sc)
+                          for c, sc in zip(cache["slots"], pre["slots"])],
+                "tail": [jax.tree.map(wr(0), c, sc)
+                         for c, sc in zip(cache["tail"], pre["tail"])]}
 
 
 class ServeEngine:
@@ -177,14 +179,15 @@ class ContinuousEngine:
         def _decode_tick(p, c, dev, act, kv):
             toks, lens, buf, pos, ok = dev
             logits, c = T.serve_decode_step(p, cfg, toks, c, lens, act, kv)
-            ok = ok & jnp.all(jnp.isfinite(logits) | ~act[:, None])
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)        # (b,)
-            toks = jnp.where(act[:, None], nxt[:, None], toks)
-            lens = lens + act
-            upd = jax.vmap(lambda b, t, i:
-                           jax.lax.dynamic_update_slice(b, t[None], (i,)))
-            buf = jnp.where(act[:, None], upd(buf, nxt, pos), buf)
-            pos = pos + act
+            with jax.named_scope("sample"):
+                ok = ok & jnp.all(jnp.isfinite(logits) | ~act[:, None])
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)    # (b,)
+                toks = jnp.where(act[:, None], nxt[:, None], toks)
+                lens = lens + act
+                upd = jax.vmap(lambda b, t, i:
+                               jax.lax.dynamic_update_slice(b, t[None], (i,)))
+                buf = jnp.where(act[:, None], upd(buf, nxt, pos), buf)
+                pos = pos + act
             return c, (toks, lens, buf, pos, ok), nxt
 
         def _chunk_tick(p, toks, c, dev, off, slot, last, kv):
@@ -192,18 +195,19 @@ class ContinuousEngine:
             logits, sub = T.chunk_step(p, cfg, toks, sub, off, kv)
             c = T.cache_slot_write(c, sub, slot)
             t, lens, buf, pos, ok = dev
-            ok = ok & jnp.all(jnp.isfinite(logits))
-            sl = jnp.arange(t.shape[0]) == slot
-            end = off + toks.shape[1]
-            lens = jnp.where(sl, end, lens)
-            # final chunk: its last-position logits seed decoding
-            ctok = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-            fin = sl & last
-            t = jnp.where(fin[:, None], ctok, t)
-            buf = jnp.where(fin[:, None]
-                            & (jnp.arange(buf.shape[1]) == 0)[None, :],
-                            ctok, buf)
-            pos = jnp.where(fin, 1, pos)
+            with jax.named_scope("sample"):
+                ok = ok & jnp.all(jnp.isfinite(logits))
+                sl = jnp.arange(t.shape[0]) == slot
+                end = off + toks.shape[1]
+                lens = jnp.where(sl, end, lens)
+                # final chunk: its last-position logits seed decoding
+                ctok = jnp.argmax(logits[0, -1]).astype(jnp.int32)
+                fin = sl & last
+                t = jnp.where(fin[:, None], ctok, t)
+                buf = jnp.where(fin[:, None]
+                                & (jnp.arange(buf.shape[1]) == 0)[None, :],
+                                ctok, buf)
+                pos = jnp.where(fin, 1, pos)
             return c, (t, lens, buf, pos, ok), logits[0, -1]
 
         def _mixed_tick(p, toks, c, dev, act, off, slot, last, kv_d, kv_p):
@@ -277,37 +281,52 @@ class ContinuousEngine:
 
     def _set_state(self, i: int, st: int):
         self.state[i] = st
-        self._active = jnp.asarray([s == DECODE for s in self.state])
+        with jax.profiler.TraceAnnotation("engine.upload"):
+            self._active = jnp.asarray([s == DECODE for s in self.state])
 
     def _admit_free_slots(self):
-        for i in range(self.batch):
-            if not self.queue:
-                return
-            if self.state[i] == FREE:
-                req = self.queue.popleft()
-                # reused-slot hygiene: drop every cache row the previous
-                # occupant wrote before the new request's chunks land
-                self.cache, self.dev = self._admit(self.cache, self.dev,
-                                                   jnp.int32(i))
-                self.slots[i] = req
-                self._set_state(i, PREFILL)
-                self.prefill_pos[i] = 0
-                self.lengths[i] = 0
-                self.gen_count[i] = 0
+        if not self.queue:
+            return
+        with jax.profiler.TraceAnnotation("engine.admit"):
+            for i in range(self.batch):
+                if not self.queue:
+                    return
+                if self.state[i] == FREE:
+                    req = self.queue.popleft()
+                    req.t_admit = time.time()
+                    # reused-slot hygiene: drop every cache row the previous
+                    # occupant wrote before the new request's chunks land
+                    self.cache, self.dev = self._admit(self.cache, self.dev,
+                                                       jnp.int32(i))
+                    self.slots[i] = req
+                    self._set_state(i, PREFILL)
+                    self.prefill_pos[i] = 0
+                    self.lengths[i] = 0
+                    self.gen_count[i] = 0
 
     def _bucket(self, need: int) -> int:
         return min(-(-need // self.page) * self.page, self.max_len)
 
     # -- one engine tick -------------------------------------------------
+    # Host spans, in the profiler's trace on the device ops' clock:
+    # ``engine.tick`` holds ``engine.admit``, ``engine.dispatch`` (the tick
+    # program's arguments and its call) and ``engine.retire`` (the
+    # bookkeeping after the call); ``engine.fetch`` marks each blocking
+    # device read, ``engine.upload`` each upload of the active-slot mask.
     def tick(self) -> bool:
         """Run one engine iteration; returns True if any work was done."""
-        pallas_mode.configure(**self._mode_kw)
-        self._admit_free_slots()
+        if not self.queue and all(s == FREE for s in self.state):
+            return False
+        with jax.profiler.TraceAnnotation("engine.tick"):
+            pallas_mode.configure(**self._mode_kw)
+            self._admit_free_slots()
+            self._step()
+        return True
+
+    def _step(self):
         decoding = [i for i in range(self.batch) if self.state[i] == DECODE]
         prefilling = [i for i in range(self.batch)
                       if self.state[i] == PREFILL]
-        if not decoding and not prefilling:
-            return False
         self.ticks += 1
         nxt_dev = None
 
@@ -323,79 +342,86 @@ class ContinuousEngine:
             k = min(rem, 16)
             k = 1 << (k.bit_length() - 1)           # quantize: few traces
             if k > 1:
-                kv = self._bucket(max(self.lengths[i]
-                                      for i in decoding) + k)
-                self.cache, self.dev = self._decode_k(
-                    self.params, self.cache, self.dev, self._active, kv, k)
-                self.ticks += k - 1
-                self.ticks_decode += k
-                for i in decoding:
-                    self.lengths[i] += k
-                    self.gen_count[i] += k
-                    self._maybe_retire(i)
-                return True
+                with jax.profiler.TraceAnnotation("engine.dispatch"):
+                    kv = self._bucket(max(self.lengths[i]
+                                          for i in decoding) + k)
+                    self.cache, self.dev = self._decode_k(
+                        self.params, self.cache, self.dev, self._active,
+                        kv, k)
+                with jax.profiler.TraceAnnotation("engine.retire"):
+                    self.ticks += k - 1
+                    self.ticks_decode += k
+                    for i in decoding:
+                        self.lengths[i] += k
+                        self.gen_count[i] += k
+                        self._maybe_retire(i)
+                return
 
-        kv_d = (self._bucket(max(self.lengths[i] for i in decoding) + 1)
-                if decoding else 0)
-        ci = prefilling[0] if prefilling else None
-        if ci is not None:
-            req = self.slots[ci]
-            off = self.prefill_pos[ci]
-            c = min(self.chunk, req.prompt.shape[1] - off)
-            toks = req.prompt[:, off:off + c]
-            kv_p = self._bucket(off + c)
-            last = off + c == req.prompt.shape[1]
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            kv_d = (self._bucket(max(self.lengths[i] for i in decoding) + 1)
+                    if decoding else 0)
+            ci = prefilling[0] if prefilling else None
+            if ci is not None:
+                req = self.slots[ci]
+                off = self.prefill_pos[ci]
+                c = min(self.chunk, req.prompt.shape[1] - off)
+                toks = req.prompt[:, off:off + c]
+                kv_p = self._bucket(off + c)
+                last = off + c == req.prompt.shape[1]
 
-        if decoding and ci is not None:
-            self.cache, self.dev, nxt_dev, self.prefill_logits = self._mixed(
-                self.params, toks, self.cache, self.dev, self._active,
-                jnp.int32(off), jnp.int32(ci), jnp.asarray(last),
-                kv_d, kv_p)
-            self.ticks_decode += 1
-            self.ticks_prefill += 1
-            self.ticks_overlap += 1
-        elif decoding:
-            self.cache, self.dev, nxt_dev = self._decode(
-                self.params, self.cache, self.dev, self._active, kv_d)
-            self.ticks_decode += 1
-        else:
-            self.cache, self.dev, self.prefill_logits = self._chunk(
-                self.params, toks, self.cache, self.dev, jnp.int32(off),
-                jnp.int32(ci), jnp.asarray(last), kv_p)
-            self.ticks_prefill += 1
+            if decoding and ci is not None:
+                (self.cache, self.dev, nxt_dev,
+                 self.prefill_logits) = self._mixed(
+                    self.params, toks, self.cache, self.dev, self._active,
+                    jnp.int32(off), jnp.int32(ci), jnp.asarray(last),
+                    kv_d, kv_p)
+                self.ticks_decode += 1
+                self.ticks_prefill += 1
+                self.ticks_overlap += 1
+            elif decoding:
+                self.cache, self.dev, nxt_dev = self._decode(
+                    self.params, self.cache, self.dev, self._active, kv_d)
+                self.ticks_decode += 1
+            else:
+                self.cache, self.dev, self.prefill_logits = self._chunk(
+                    self.params, toks, self.cache, self.dev, jnp.int32(off),
+                    jnp.int32(ci), jnp.asarray(last), kv_p)
+                self.ticks_prefill += 1
 
-        if decoding:
+        with jax.profiler.TraceAnnotation("engine.retire"):
             for i in decoding:
                 self.lengths[i] += 1
                 self.gen_count[i] += 1
-        if ci is not None:
-            self.prefill_pos[ci] = off + c
-            self.lengths[ci] = off + c
-            if last:
-                self._set_state(ci, DECODE)
-                self.gen_count[ci] = 1
+            if ci is not None:
+                self.prefill_pos[ci] = off + c
+                self.lengths[ci] = off + c
+                if last:
+                    self._set_state(ci, DECODE)
+                    self.gen_count[ci] = 1
 
-        if self.sync:
-            # per-token observation: one fetch per tick (EOS stopping /
-            # latency measurement); otherwise the loop stays async
-            nxt = jax.device_get(nxt_dev) if nxt_dev is not None else None
-            now = time.time()
-            for i in decoding:
-                req = self.slots[i]
-                req.generated.append(int(nxt[i]))
-                req.token_times.append(now)
-            if ci is not None and self.state[ci] == DECODE \
-                    and self.gen_count[ci] == 1:
-                req = self.slots[ci]
-                req.t_first = now
-                tok0 = int(jax.device_get(self.dev[0][ci, 0]))
-                req.generated.append(tok0)
-                req.token_times.append(now)
+            if self.sync:
+                # per-token observation: one fetch per tick (EOS stopping /
+                # latency measurement); otherwise the loop stays async
+                if nxt_dev is not None:
+                    with jax.profiler.TraceAnnotation("engine.fetch"):
+                        nxt = jax.device_get(nxt_dev)
+                now = time.time()
+                for i in decoding:
+                    req = self.slots[i]
+                    req.generated.append(int(nxt[i]))
+                    req.token_times.append(now)
+                if ci is not None and self.state[ci] == DECODE \
+                        and self.gen_count[ci] == 1:
+                    req = self.slots[ci]
+                    req.t_first = now
+                    with jax.profiler.TraceAnnotation("engine.fetch"):
+                        tok0 = int(jax.device_get(self.dev[0][ci, 0]))
+                    req.generated.append(tok0)
+                    req.token_times.append(now)
 
-        for i in range(self.batch):
-            if self.state[i] == DECODE:
-                self._maybe_retire(i)
-        return True
+            for i in range(self.batch):
+                if self.state[i] == DECODE:
+                    self._maybe_retire(i)
 
     def _maybe_retire(self, i: int):
         req = self.slots[i]
@@ -406,8 +432,9 @@ class ContinuousEngine:
             if not self.sync:
                 # one blocking read per request: its finished token row
                 n = self.gen_count[i]
-                req.generated = [int(x) for x in
-                                 jax.device_get(self.dev[2][i, :n])]
+                with jax.profiler.TraceAnnotation("engine.fetch"):
+                    row = jax.device_get(self.dev[2][i, :n])
+                req.generated = [int(x) for x in row]
             req.done = True
             self._set_state(i, FREE)
             self.lengths[i] = 0

@@ -121,6 +121,7 @@ def causal_mask(sq: int, window: int = 0) -> jnp.ndarray:
     return m[None]   # (1, sq, sq)
 
 
+@jax.named_scope("attention")
 def attention(p, cfg: ArchConfig, x, positions, *, window: int = 0,
               mrope_positions=None, return_kv: bool = False):
     """Training/prefill self-attention (causal, optional sliding window)."""
@@ -141,6 +142,7 @@ def attention(p, cfg: ArchConfig, x, positions, *, window: int = 0,
     return out
 
 
+@jax.named_scope("attention")
 def attention_noncausal(p, cfg: ArchConfig, x, positions) -> jnp.ndarray:
     """Encoder self-attention (bidirectional)."""
     q, k, v = _qkv(p, cfg, x, positions)
@@ -148,6 +150,7 @@ def attention_noncausal(p, cfg: ArchConfig, x, positions) -> jnp.ndarray:
     return out.reshape(x.shape[0], x.shape[1], -1) @ p["wo"]
 
 
+@jax.named_scope("attention")
 def cross_attention(p, cfg: ArchConfig, x, memory, positions) -> jnp.ndarray:
     """Decoder cross-attention over encoder memory (no rope on memory)."""
     hd = cfg.hd
@@ -172,6 +175,7 @@ def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int, layer_count: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@jax.named_scope("attention")
 def decode_attention(p, cfg: ArchConfig, x, k_cache, v_cache, cache_len,
                      *, window: int = 0, mrope_positions=None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -197,6 +201,7 @@ def decode_attention(p, cfg: ArchConfig, x, k_cache, v_cache, cache_len,
 # serving fast path: chunked prefill + ragged paged decode
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def chunk_attention(p, cfg: ArchConfig, x, k_cache, v_cache, offset, kv_len,
                     *, window: int = 0
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -233,6 +238,7 @@ def chunk_attention(p, cfg: ArchConfig, x, k_cache, v_cache, offset, kv_len,
     return out, k_cache, v_cache
 
 
+@jax.named_scope("attention")
 def paged_decode_attention(p, cfg: ArchConfig, x, k_cache, v_cache, lengths,
                            kv_len, *, window: int = 0
                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:

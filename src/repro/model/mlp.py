@@ -28,6 +28,7 @@ def init_mlp(key, d_model: int, d_ff: int, dtype) -> Dict:
     }
 
 
+@jax.named_scope("mlp")
 def mlp(p, x):
     from .pallas_mode import mode
     md = mode()
@@ -65,6 +66,7 @@ def init_moe(key, cfg: ArchConfig, dtype) -> Dict:
     return p
 
 
+@jax.named_scope("moe")
 def moe(p, cfg: ArchConfig, x, capacity_factor: float = 1.25
         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Grouped token-choice MoE. Returns (output, aux_loss). x: (b, s, d).

@@ -71,6 +71,7 @@ def _selective_ssm(p, cfg: ArchConfig, xs, return_last: bool = False):
     return y.astype(xs.dtype), (h[:, -1] if return_last else None)
 
 
+@jax.named_scope("ssm")
 def mamba(p, cfg: ArchConfig, x, return_state: bool = False):
     """Full-sequence Mamba block. x: (b, s, d)."""
     di = cfg.d_inner
@@ -108,6 +109,7 @@ def init_mamba_cache(cfg: ArchConfig, batch: int, layer_count: int, dtype) -> Di
     }
 
 
+@jax.named_scope("ssm")
 def mamba_decode(p, cfg: ArchConfig, x, conv_state, ssm_state
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One-token decode. x: (b, 1, d); conv_state: (b, cw-1, di);
@@ -133,6 +135,7 @@ def mamba_decode(p, cfg: ArchConfig, x, conv_state, ssm_state
     return out, hist[:, 1:].astype(conv_state.dtype), h
 
 
+@jax.named_scope("ssm")
 def mamba_chunk(p, cfg: ArchConfig, x, conv_state, ssm_state
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Chunked-prefill Mamba with explicit state carry: x (b, c, d) is a

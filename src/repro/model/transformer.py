@@ -373,10 +373,11 @@ def _slot_axis_map(cache, fn_slots, fn_tail):
 
 def cache_slot_view(cache: Dict, i) -> Dict:
     """Batch-size-1 view of batch slot ``i`` (traced index ok)."""
-    return _slot_axis_map(
-        cache,
-        lambda v: jax.lax.dynamic_slice_in_dim(v, i, 1, axis=1),
-        lambda v: jax.lax.dynamic_slice_in_dim(v, i, 1, axis=0))
+    with jax.named_scope("kv_cache"):
+        return _slot_axis_map(
+            cache,
+            lambda v: jax.lax.dynamic_slice_in_dim(v, i, 1, axis=1),
+            lambda v: jax.lax.dynamic_slice_in_dim(v, i, 1, axis=0))
 
 
 def cache_slot_write(cache: Dict, sub: Dict, i) -> Dict:
@@ -386,10 +387,11 @@ def cache_slot_write(cache: Dict, sub: Dict, i) -> Dict:
     def wr(axis):
         return lambda v, s: jax.lax.dynamic_update_slice_in_dim(
             v, s.astype(v.dtype), i, axis=axis)
-    return {"slots": [jax.tree.map(wr(1), c, sc)
-                      for c, sc in zip(cache["slots"], sub["slots"])],
-            "tail": [jax.tree.map(wr(0), c, sc)
-                     for c, sc in zip(cache["tail"], sub["tail"])]}
+    with jax.named_scope("kv_cache"):
+        return {"slots": [jax.tree.map(wr(1), c, sc)
+                          for c, sc in zip(cache["slots"], sub["slots"])],
+                "tail": [jax.tree.map(wr(0), c, sc)
+                         for c, sc in zip(cache["tail"], sub["tail"])]}
 
 
 def zero_cache_slot(cache: Dict, i) -> Dict:
@@ -402,7 +404,8 @@ def zero_cache_slot(cache: Dict, i) -> Dict:
             return jax.lax.dynamic_update_slice_in_dim(
                 v, jnp.zeros_like(row), i, axis=axis)
         return go
-    return _slot_axis_map(cache, z(1), z(0))
+    with jax.named_scope("kv_cache"):
+        return _slot_axis_map(cache, z(1), z(0))
 
 
 def _decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, cache_len,
@@ -506,11 +509,21 @@ def _chunk_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, offset,
 
 def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn):
     """Shared slot-scan + tail walk for the serving step functions:
-    ``layer_fn(p, spec, x, layer_cache) -> (x, new_layer_cache)``."""
+    ``layer_fn(p, spec, x, layer_cache) -> (x, new_layer_cache)``.
+
+    Named scopes: each layer's own operations are ``layer`` (with the
+    layer functions' ``attention``/``ssm``/``mlp``/``moe`` inside); the
+    walk's own, which slice each layer's weights and cache out of the
+    stacks, restack the new cache and carry both, are ``kv_cache``."""
     specs = layer_specs(cfg, "decoder")
     period = pattern_period(cfg, "decoder")
     repeats = len(specs) // period
     new_cache: Dict[str, Any] = {"slots": [], "tail": []}
+
+    def layer(p, spec, xc, lc):
+        with jax.named_scope("layer"):
+            return layer_fn(p, spec, xc, lc)
+
     if repeats:
         def body(carry, xs):
             xc = carry
@@ -518,21 +531,22 @@ def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn):
             new_slots = []
             for s in range(period):
                 p_s = gather_params_for_compute(slot_params[s])
-                xc, nc = layer_fn(p_s, specs[s], xc, slot_caches[s])
+                xc, nc = layer(p_s, specs[s], xc, slot_caches[s])
                 new_slots.append(nc)
             return xc, tuple(new_slots)
         scan_xs = (tuple(params["decoder"]["slots"]), tuple(cache["slots"]))
-        if UNROLL:
-            ys_list = []
-            for r in range(repeats):
-                x, y = body(x, jax.tree.map(lambda v: v[r], scan_xs))
-                ys_list.append(y)
-            new_slots = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
-        else:
-            x, new_slots = jax.lax.scan(body, x, scan_xs)
+        with jax.named_scope("kv_cache"):
+            if UNROLL:
+                ys_list = []
+                for r in range(repeats):
+                    x, y = body(x, jax.tree.map(lambda v: v[r], scan_xs))
+                    ys_list.append(y)
+                new_slots = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
+            else:
+                x, new_slots = jax.lax.scan(body, x, scan_xs)
         new_cache["slots"] = list(new_slots)
     for i, p in enumerate(params["decoder"]["tail"]):
-        x, nc = layer_fn(p, specs[repeats * period + i], x, cache["tail"][i])
+        x, nc = layer(p, specs[repeats * period + i], x, cache["tail"][i])
         new_cache["tail"].append(nc)
     return x, new_cache
 
@@ -547,15 +561,17 @@ def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
     Returns (logits (b, c, vocab) for *every* chunk position — the
     caller picks the last real one to seed decoding — and the updated
     cache)."""
-    x = embed(tokens, params["embed"])
+    with jax.named_scope("embed"):
+        x = embed(tokens, params["embed"])
     x = shard_activation(x, ("batch", "seq", None))
     x, new_cache = _stack_walk(
         params, cfg, x, cache,
         lambda p, spec, xc, lc: _chunk_layer(p, spec, cfg, xc, lc, offset,
                                              kv_len))
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    head = params.get("lm_head", params["embed"])
-    logits = unembed(x, head)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        head = params.get("lm_head", params["embed"])
+        logits = unembed(x, head)
     return logits, new_cache
 
 
@@ -599,14 +615,16 @@ def serve_decode_step(params, cfg: ArchConfig, token: jnp.ndarray,
     ``max(lengths)``); active: (b,) bool — slots currently decoding;
     kv_len: static page-aligned bound ≥ max(lengths)+1.  Returns
     (logits (b, vocab), new cache)."""
-    x = embed(token, params["embed"])
+    with jax.named_scope("embed"):
+        x = embed(token, params["embed"])
     x, new_cache = _stack_walk(
         params, cfg, x, cache,
         lambda p, spec, xc, lc: _serve_decode_layer(p, spec, cfg, xc, lc,
                                                     lengths, active, kv_len))
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    head = params.get("lm_head", params["embed"])
-    logits = unembed(x[:, 0, :], head)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        head = params.get("lm_head", params["embed"])
+        logits = unembed(x[:, 0, :], head)
     return logits, new_cache
 
 

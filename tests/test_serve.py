@@ -10,6 +10,7 @@ Pallas kernels must not change a single argmax.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -183,6 +184,28 @@ def test_submit_validation():
         eng.submit(Request(0, prompt(1, 16)))
     with pytest.raises(ValueError, match="exceeds token buffer"):
         eng.submit(Request(1, prompt(1, 4), max_new=12))
+
+
+def test_admission_is_stamped_between_submit_and_first_token():
+    """Each request's admission stamp falls between its submission and
+    its first token, also for requests that waited for a slot."""
+    prompts = [prompt(i, 8) for i in range(4)]
+    _, reqs = run_continuous(prompts, 4, 32, batch=2, chunk=8, sync=True)
+    assert all(r.done for r in reqs)
+    for r in reqs:
+        assert 0 < r.t_submit <= r.t_admit <= r.t_first, r
+
+
+def test_chunk_program_carries_named_scopes():
+    """The prefill-chunk tick program's operations carry the model's
+    named scopes in their name stacks, which the profiler's trace
+    keeps for each device operation."""
+    eng = ContinuousEngine(CFG, params(), 2, 32, chunk=8, max_new=4)
+    text = eng.lower_chunk(8, 16).as_text(debug_info=True)
+    for name in ("embed", "layer", "attention", "mlp", "kv_cache", "head",
+                 "sample"):
+        # a component of some operation's name stack (a location)
+        assert re.search(rf'loc\("([^"]*/)?{name}/', text), name
 
 
 def test_mamba_chunked_prefill_state_carry():
