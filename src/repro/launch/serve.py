@@ -449,7 +449,10 @@ class ContinuousEngine:
     def reset(self):
         """Back to the post-init state, keeping compiled tick functions."""
         b = self.batch
-        self.cache = jax.tree.map(jnp.zeros_like, self.cache)
+        # drop the old cache before the new one is made: both at once
+        # would hold a second cache's worth of device memory
+        self.cache = None
+        self.cache = T.init_cache(self.cfg, b, self.max_len)
         self.dev = self._fresh_dev(self.dev[2].shape[1])
         self.prefill_logits = None
         self.lengths = [0] * b

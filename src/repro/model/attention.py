@@ -202,27 +202,28 @@ def decode_attention(p, cfg: ArchConfig, x, k_cache, v_cache, cache_len,
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("attention")
-def chunk_attention(p, cfg: ArchConfig, x, k_cache, v_cache, offset, kv_len,
-                    *, window: int = 0
+def chunk_attention(p, cfg: ArchConfig, x, k_prefix, v_prefix, offset, *,
+                    window: int = 0
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Chunked-prefill self-attention: x (b, c, d) holds rows
     ``[offset, offset+c)`` of the sequence (``offset`` a traced scalar);
-    the chunk's k/v are written into the cache at ``offset`` and
-    attention runs causally over ``cache[:, :kv_len]`` — ``kv_len`` the
-    static page-aligned prefix covering ``offset + c`` (unwritten rows
-    beyond the diagonal are masked, so the page bound is exact).  The
-    Pallas route uses the flash kernel's SMEM ``q_offset``: one compiled
-    kernel serves every chunk position.  Returns (out, k_cache, v_cache).
-    """
+    ``k_prefix``/``v_prefix`` (b, kv_len, hkv, hd) are the cache's first
+    ``kv_len`` rows — the static page-aligned prefix covering
+    ``offset + c``, all rows < offset already prefilled.  The chunk's
+    k/v are put into the prefix at ``offset`` and attention runs
+    causally over it (unwritten rows beyond the diagonal are masked, so
+    the page bound is exact).  The Pallas route uses the flash kernel's
+    SMEM ``q_offset``: one compiled kernel serves every chunk position.
+    Returns (out, k_rows, v_rows): the chunk's own (b, c, hkv, hd) rows,
+    in the cache's dtype, for the caller to write at ``offset``."""
     b, c, _ = x.shape
+    kv_len = k_prefix.shape[1]
     positions = jnp.broadcast_to(offset + jnp.arange(c)[None, :], (b, c))
     q, k_new, v_new = _qkv(p, cfg, x, positions)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k_new.astype(k_cache.dtype), offset, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v_new.astype(v_cache.dtype), offset, axis=1)
-    kp = k_cache[:, :kv_len]
-    vp = v_cache[:, :kv_len]
+    k_new = k_new.astype(k_prefix.dtype)
+    v_new = v_new.astype(v_prefix.dtype)
+    kp = jax.lax.dynamic_update_slice_in_dim(k_prefix, k_new, offset, axis=1)
+    vp = jax.lax.dynamic_update_slice_in_dim(v_prefix, v_new, offset, axis=1)
     md = _pallas()
     if md.enabled and window == 0 and c >= md.min_attn_q:
         out = _flash(q, kp, vp, q_offset=offset)
@@ -235,36 +236,41 @@ def chunk_attention(p, cfg: ArchConfig, x, k_cache, v_cache, offset, kv_len,
         out = _sdpa(q, kp, vp, jnp.broadcast_to(m[None], (b, c, kv_len)),
                     cfg.n_heads // cfg.n_kv_heads)
     out = out.reshape(b, c, -1) @ p["wo"]
-    return out, k_cache, v_cache
+    return out, k_new, v_new
 
 
 @jax.named_scope("attention")
-def paged_decode_attention(p, cfg: ArchConfig, x, k_cache, v_cache, lengths,
-                           kv_len, *, window: int = 0
+def paged_decode_attention(p, cfg: ArchConfig, x, k_prefix, v_prefix,
+                           lengths, *, window: int = 0
                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Ragged one-token decode over a page-aligned KV prefix.
 
-    x: (b, 1, d); lengths: (b,) int32 per-slot valid lengths (each
-    slot's token is written at its own ``lengths[i]`` — no shared
-    ``max(lengths)`` that would expose stale rows in shorter slots);
-    ``kv_len``: static, attention reads only ``cache[:, :kv_len]``.
-    Bit-identical to :func:`decode_attention` over the full cache —
-    masked entries contribute exact zeros to the softmax — while moving
-    only the used pages.  Returns (out, k_cache, v_cache)."""
+    x: (b, 1, d); ``k_prefix``/``v_prefix`` (b, kv_len, hkv, hd): the
+    cache's first ``kv_len`` rows (static, page-aligned); lengths: (b,)
+    int32 per-slot valid lengths (each slot's token goes at its own
+    ``lengths[i]`` — no shared ``max(lengths)`` that would expose stale
+    rows in shorter slots).  Bit-identical to :func:`decode_attention`
+    over the full cache — masked entries contribute exact zeros to the
+    softmax — while moving only the used pages.  Returns (out, k_row,
+    v_row): each slot's own (b, 1, hkv, hd) row, in the cache's dtype,
+    for the caller to write at ``lengths``."""
     b = x.shape[0]
+    kv_len = k_prefix.shape[1]
     positions = lengths[:, None].astype(jnp.int32)
     q, k_new, v_new = _qkv(p, cfg, x, positions)
-    upd = jax.vmap(
-        lambda c, n, l: jax.lax.dynamic_update_slice_in_dim(c, n, l, axis=0))
-    k_cache = upd(k_cache, k_new.astype(k_cache.dtype), lengths)
-    v_cache = upd(v_cache, v_new.astype(v_cache.dtype), lengths)
-    kp = k_cache[:, :kv_len]
-    vp = v_cache[:, :kv_len]
+    k_new = k_new.astype(k_prefix.dtype)
+    v_new = v_new.astype(v_prefix.dtype)
+    # the new row enters the prefix by a select, which fuses into the
+    # read; a slot whose row lies past the prefix (an inactive slot
+    # beyond the page bound) leaves the prefix as it is
     j = jnp.arange(kv_len)[None, None, :]
+    at = (j[0] == lengths[:, None])[:, :, None, None]
+    kp = jnp.where(at, k_new, k_prefix)
+    vp = jnp.where(at, v_new, v_prefix)
     mask = j <= lengths[:, None, None]
     if window:
         mask &= j > (lengths[:, None, None] - window)
     out = _sdpa(q, kp, vp, jnp.broadcast_to(mask, (b, 1, kv_len)),
                 cfg.n_heads // cfg.n_kv_heads)
     out = out.reshape(b, 1, -1) @ p["wo"]
-    return out, k_cache, v_cache
+    return out, k_new, v_new

@@ -486,35 +486,71 @@ def decode_step(params, cfg: ArchConfig, token: jnp.ndarray, cache: Dict,
 # serving fast path: chunked prefill + ragged paged decode
 # ---------------------------------------------------------------------------
 
-def _chunk_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, offset,
-                 kv_len):
+def _chunk_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, offset):
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if spec.mixer == "attn":
-        h, k_all, v_all = ATT.chunk_attention(
-            p["mixer"], cfg, h, cache["k"], cache["v"], offset, kv_len,
+        h, k_rows, v_rows = ATT.chunk_attention(
+            p["mixer"], cfg, h, cache["k"], cache["v"], offset,
             window=spec.window)
-        new_cache = {"k": k_all, "v": v_all}
+        out = {"k": k_rows, "v": v_rows}
     else:
         h, conv, ssm_st = SSM.mamba_chunk(p["mixer"], cfg, h,
                                           cache["conv"], cache["ssm"])
-        new_cache = {"conv": conv, "ssm": ssm_st}
+        out = {"conv": conv, "ssm": ssm_st}
     x = x + h
     if spec.ffn == "mlp":
         x = x + MLP.mlp(p["ffn"], rmsnorm(x, p["ln2"], cfg.norm_eps))
     elif spec.ffn == "moe":
         h, _ = MLP.moe(p["ffn"], cfg, rmsnorm(x, p["ln2"], cfg.norm_eps))
         x = x + h
-    return x, new_cache
+    return x, out
 
 
-def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn):
+def _kv_prefix(v, layer, kv_len: int):
+    """Rows ``[:kv_len]`` of one layer's K or V: of layer ``layer`` (a
+    traced index) of a stacked (repeats, b, S, hkv, hd) cache, or of a
+    tail layer's (b, S, hkv, hd) cache when ``layer`` is None."""
+    if layer is None:
+        return v[:, :kv_len]
+    return jax.lax.dynamic_slice(
+        v, (layer, 0, 0, 0, 0), (1, v.shape[1], kv_len) + v.shape[3:])[0]
+
+
+def _put_rows(v, rows, starts):
+    """Write ``rows`` (..., b, n, hkv, hd) into the cache ``v`` (..., b,
+    S, hkv, hd), slot ``i``'s at row ``starts[i]`` (a scalar ``starts``
+    serves every slot): one ``dynamic_update_slice`` of the rows alone
+    per slot, in place on a donated cache."""
+    rows = rows.astype(v.dtype)
+    lead = (0,) * (v.ndim - 4)
+    b = v.shape[-4]
+    starts = jnp.broadcast_to(starts, (b,))
+    for i in range(b):
+        v = jax.lax.dynamic_update_slice(
+            v, rows[..., i:i + 1, :, :, :], lead + (i, starts[i], 0, 0))
+    return v
+
+
+def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn, kv_len: int,
+                starts):
     """Shared slot-scan + tail walk for the serving step functions:
-    ``layer_fn(p, spec, x, layer_cache) -> (x, new_layer_cache)``.
+    ``layer_fn(p, spec, x, layer_cache) -> (x, out)``.
+
+    An attention layer is handed its K/V prefix ``[:, :kv_len]`` and
+    returns only the K/V rows it produced; the walk writes them once, in
+    place, at row ``starts`` (:func:`_put_rows`) — after the scan for
+    the stacked layers, after the layer for a tail layer.  The stacked
+    K/V are a loop-invariant operand of the scan, each layer reading its
+    prefix by dynamic index, so no whole-layer cache passes through the
+    scan or is restacked.  An SSM layer rewrites its whole state on
+    every step: it is handed that state and returns it whole, through
+    the scan's ``xs``/``ys``.
 
     Named scopes: each layer's own operations are ``layer`` (with the
     layer functions' ``attention``/``ssm``/``mlp``/``moe`` inside); the
-    walk's own, which slice each layer's weights and cache out of the
-    stacks, restack the new cache and carry both, are ``kv_cache``."""
+    walk's own — the scan that slices each layer's weights, the prefix
+    reads, the SSM states' restacking and the row writes — are
+    ``kv_cache``."""
     specs = layer_specs(cfg, "decoder")
     period = pattern_period(cfg, "decoder")
     repeats = len(specs) // period
@@ -524,30 +560,51 @@ def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn):
         with jax.named_scope("layer"):
             return layer_fn(p, spec, xc, lc)
 
+    def prefix(c, r):
+        with jax.named_scope("kv_cache"):
+            return jax.tree.map(lambda v: _kv_prefix(v, r, kv_len), c)
+
+    def put(c, rows):
+        with jax.named_scope("kv_cache"):
+            return jax.tree.map(lambda v, n: _put_rows(v, n, starts), c, rows)
+
     if repeats:
+        kv = [specs[s].mixer == "attn" for s in range(period)]
+        slots = cache["slots"]
+
         def body(carry, xs):
             xc = carry
-            slot_params, slot_caches = xs
-            new_slots = []
+            r, slot_params, states = xs
+            outs = []
             for s in range(period):
                 p_s = gather_params_for_compute(slot_params[s])
-                xc, nc = layer(p_s, specs[s], xc, slot_caches[s])
-                new_slots.append(nc)
-            return xc, tuple(new_slots)
-        scan_xs = (tuple(params["decoder"]["slots"]), tuple(cache["slots"]))
+                lc = prefix(slots[s], r) if kv[s] else states[s]
+                xc, out = layer(p_s, specs[s], xc, lc)
+                outs.append(out)
+            return xc, tuple(outs)
+        states = tuple(None if kv[s] else slots[s] for s in range(period))
+        scan_xs = (jnp.arange(repeats), tuple(params["decoder"]["slots"]),
+                   states)
         with jax.named_scope("kv_cache"):
             if UNROLL:
                 ys_list = []
                 for r in range(repeats):
                     x, y = body(x, jax.tree.map(lambda v: v[r], scan_xs))
                     ys_list.append(y)
-                new_slots = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
+                outs = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
             else:
-                x, new_slots = jax.lax.scan(body, x, scan_xs)
-        new_cache["slots"] = list(new_slots)
+                x, outs = jax.lax.scan(body, x, scan_xs)
+        new_cache["slots"] = [put(slots[s], outs[s]) if kv[s] else outs[s]
+                              for s in range(period)]
     for i, p in enumerate(params["decoder"]["tail"]):
-        x, nc = layer(p, specs[repeats * period + i], x, cache["tail"][i])
-        new_cache["tail"].append(nc)
+        spec = specs[repeats * period + i]
+        c = cache["tail"][i]
+        if spec.mixer == "attn":
+            x, rows = layer(p, spec, x, prefix(c, None))
+            c = put(c, rows)
+        else:
+            x, c = layer(p, spec, x, c)
+        new_cache["tail"].append(c)
     return x, new_cache
 
 
@@ -557,17 +614,18 @@ def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
 
     tokens: (b, c) — rows ``[offset, offset+c)`` of the prompt (offset a
     traced scalar, 0 for the first chunk); cache: (typically a b=1
-    :func:`cache_slot_view`) with all rows < offset already prefilled.
+    :func:`cache_slot_view`) with all rows < offset already prefilled;
+    kv_len: static page-aligned prefix covering ``offset + c``.
     Returns (logits (b, c, vocab) for *every* chunk position — the
-    caller picks the last real one to seed decoding — and the updated
-    cache)."""
+    caller picks the last real one to seed decoding — and the cache with
+    the chunk's K/V rows and the SSM states updated)."""
     with jax.named_scope("embed"):
         x = embed(tokens, params["embed"])
     x = shard_activation(x, ("batch", "seq", None))
     x, new_cache = _stack_walk(
         params, cfg, x, cache,
-        lambda p, spec, xc, lc: _chunk_layer(p, spec, cfg, xc, lc, offset,
-                                             kv_len))
+        lambda p, spec, xc, lc: _chunk_layer(p, spec, cfg, xc, lc, offset),
+        kv_len, offset)
     with jax.named_scope("head"):
         x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
         head = params.get("lm_head", params["embed"])
@@ -576,16 +634,16 @@ def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
 
 
 def _serve_decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache,
-                        lengths, active, kv_len):
+                        lengths, active):
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if spec.mixer == "attn":
-        h, k_all, v_all = ATT.paged_decode_attention(
-            p["mixer"], cfg, h, cache["k"], cache["v"], lengths, kv_len,
+        h, k_row, v_row = ATT.paged_decode_attention(
+            p["mixer"], cfg, h, cache["k"], cache["v"], lengths,
             window=spec.window)
         # inactive slots (mid-prefill / retired) write at their own
         # lengths[i] — a row the next prefill chunk or admission zeroing
         # overwrites, so no select is needed on the KV pages
-        new_cache = {"k": k_all, "v": v_all}
+        out = {"k": k_row, "v": v_row}
     else:
         h, conv, ssm_st = SSM.mamba_decode(p["mixer"], cfg, h,
                                            cache["conv"], cache["ssm"])
@@ -593,15 +651,15 @@ def _serve_decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache,
         # a garbage decode update would corrupt the next chunk, so keep
         # inactive slots' states untouched
         sel = active[:, None, None]
-        new_cache = {"conv": jnp.where(sel, conv, cache["conv"]),
-                     "ssm": jnp.where(sel, ssm_st, cache["ssm"])}
+        out = {"conv": jnp.where(sel, conv, cache["conv"]),
+               "ssm": jnp.where(sel, ssm_st, cache["ssm"])}
     x = x + h
     if spec.ffn == "mlp":
         x = x + MLP.mlp(p["ffn"], rmsnorm(x, p["ln2"], cfg.norm_eps))
     elif spec.ffn == "moe":
         h, _ = MLP.moe(p["ffn"], cfg, rmsnorm(x, p["ln2"], cfg.norm_eps))
         x = x + h
-    return x, new_cache
+    return x, out
 
 
 def serve_decode_step(params, cfg: ArchConfig, token: jnp.ndarray,
@@ -614,13 +672,15 @@ def serve_decode_step(params, cfg: ArchConfig, token: jnp.ndarray,
     (each slot attends to and extends its *own* prefix — no shared
     ``max(lengths)``); active: (b,) bool — slots currently decoding;
     kv_len: static page-aligned bound ≥ max(lengths)+1.  Returns
-    (logits (b, vocab), new cache)."""
+    (logits (b, vocab), the cache with one K/V row per slot written at
+    ``lengths`` and the active slots' SSM states updated)."""
     with jax.named_scope("embed"):
         x = embed(token, params["embed"])
     x, new_cache = _stack_walk(
         params, cfg, x, cache,
         lambda p, spec, xc, lc: _serve_decode_layer(p, spec, cfg, xc, lc,
-                                                    lengths, active, kv_len))
+                                                    lengths, active),
+        kv_len, lengths)
     with jax.named_scope("head"):
         x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
         head = params.get("lm_head", params["embed"])
