@@ -34,6 +34,7 @@ class ArchConfig:
     d_inner_mult: int = 2
     dt_rank: int = 0         # 0 → d_model // 16
     conv_width: int = 4
+    bcdt_rms_eps: float = 0.0  # weight-free RMSNorm of Δ's input, B, C (0 = off)
     # attention flavour
     qk_norm: bool = False
     sliding_window: int = 0
@@ -47,6 +48,7 @@ class ArchConfig:
     # numerics & distribution policy
     dtype: str = "bfloat16"
     norm_eps: float = 1e-6
+    residual_f32: bool = False    # carry the residual stream in float32
     fsdp: bool = False            # shard params over the data axis too
     sub_quadratic: bool = False   # eligible for long_500k
     tie_embeddings: bool = False

@@ -19,7 +19,9 @@ Two engines share the model's step functions:
   chunks interleaved with decode ticks (a long prompt never stalls
   in-flight decodes), ragged per-slot cache lengths, and paged KV — the
   decode tick reads only the page-aligned used prefix of the cache, page
-  size from ``plan_attention``'s k tile.  One host sync per tick.  With
+  size from ``plan_attention``'s k tile (a stack with no attention layer
+  reads no KV and takes one bound, ``max_len``, for every tick, so each
+  tick kind compiles once).  One host sync per tick.  With
   ``use_pallas=True`` the model layers route through the Pallas kernels
   (flash attention with the SMEM q-offset for prefill chunks, the fused
   scan+gate kernel for Mamba archs) — see :mod:`repro.model.pallas_mode`.
@@ -123,6 +125,11 @@ class ServeEngine:
 FREE, PREFILL, DECODE = 0, 1, 2
 
 
+def attends(cfg) -> bool:
+    """True if some decoder layer is attention, i.e. reads KV rows."""
+    return any(s.mixer == "attn" for s in T.layer_specs(cfg, "decoder"))
+
+
 class ContinuousEngine:
     """Continuous-batching engine: per-request admission, chunked
     prefill interleaved with decode ticks, ragged paged KV.
@@ -152,11 +159,17 @@ class ContinuousEngine:
         # pallas_opts: extra PallasMode fields (threshold overrides for
         # small-shape parity tests; see model/pallas_mode.py)
         self._mode_kw = dict(enabled=use_pallas, **(pallas_opts or {}))
-        # paged-KV geometry from the scheduler: the attention plan's k
-        # tile is the unit the flash kernel streams, so pages align with
-        # kernel blocks and the page bound costs no masking slop
-        plan = akg.plan_attention(max(chunk, 8), max_len, cfg.hd)
-        self.page = page or max(min(plan.tile.get("kk", 128), max_len), 8)
+        if attends(cfg):
+            # paged-KV geometry from the scheduler: the attention plan's
+            # k tile is the unit the flash kernel streams, so pages align
+            # with kernel blocks and the page bound costs no masking slop
+            plan = akg.plan_attention(max(chunk, 8), max_len, cfg.hd)
+            self.page = page or max(min(plan.tile.get("kk", 128), max_len),
+                                    8)
+        else:
+            # no layer reads KV rows: one bound for every tick, so each
+            # tick kind compiles once
+            self.page = page or max_len
 
         self.cache = T.init_cache(cfg, batch, max_len)
         # device-resident decode state: (tokens (b,1), lengths (b,),
@@ -483,8 +496,9 @@ def warm_kernel_plans(cfg, max_len: int, chunk: int = 16) -> int:
 
     client = maybe_client()
     rows = max(chunk, 8)
-    plans = [akg.plan_attention(max_len, max_len, cfg.hd),
-             akg.plan_attention(rows, max_len, cfg.hd)]
+    plans = ([akg.plan_attention(max_len, max_len, cfg.hd),
+              akg.plan_attention(rows, max_len, cfg.hd)]
+             if attends(cfg) else [])
     if cfg.d_ff:
         plans += [akg.plan_matmul(rows, cfg.d_ff, cfg.d_model),
                   akg.plan_matmul(rows, cfg.d_model, cfg.d_ff)]

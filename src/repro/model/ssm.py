@@ -3,7 +3,9 @@
 Sequence processing uses an associative scan over the diagonal SSM
 recurrence h_t = a_t ⊙ h_{t-1} + b_t (a_t = exp(Δ_t·A)), which is both
 TPU-friendly (log-depth) and exact. Decode keeps (conv_state, ssm_state)
-as the cache.
+as the cache.  Prefill, chunked prefill and decode share one
+discretisation (``_ssm_inputs``, named scope ``ssm_inputs``), which holds
+Falcon-Mamba's B/C/Δ norm where the config sets ``bcdt_rms_eps``.
 """
 from __future__ import annotations
 
@@ -13,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.registry import ArchConfig
-from .layers import dense_init
+from .layers import dense_init, rmsnorm
 from .sharding import shard_activation
 
 
@@ -42,15 +44,21 @@ def _ssm_scan(a, b):
     return jax.lax.associative_scan(combine, (a, b), axis=1)
 
 
+@jax.named_scope("ssm_inputs")
 def _ssm_inputs(p, cfg: ArchConfig, xs):
     """Input-dependent recurrence coefficients from post-conv
-    activations xs (b, s, di): (a_bar, b_bar (b, s, di, st), Cm (b, s, st))."""
+    activations xs (..., di): (a_bar, b_bar (..., di, st), Cm (..., st)).
+    With ``cfg.bcdt_rms_eps`` set, Δ's low-rank input, B and C each go
+    through a weight-free RMSNorm first (Falcon-Mamba)."""
     st, dtr = cfg.ssm_state, cfg.dt_rank_
-    proj = xs @ p["x_proj"]                                     # (b, s, dtr+2st)
+    proj = xs @ p["x_proj"]                                     # (..., dtr+2st)
     dt_r, Bm, Cm = jnp.split(proj.astype(jnp.float32), [dtr, dtr + st], axis=-1)
+    if cfg.bcdt_rms_eps:
+        dt_r, Bm, Cm = (rmsnorm(v, jnp.zeros(()), cfg.bcdt_rms_eps)
+                          for v in (dt_r, Bm, Cm))
     dt = jax.nn.softplus(dt_r @ p["dt_proj"].astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["a_log"])                                    # (di, st)
-    a_bar = jnp.exp(dt[..., None] * A)                          # (b, s, di, st)
+    a_bar = jnp.exp(dt[..., None] * A)                          # (..., di, st)
     b_bar = (dt[..., None] * Bm[..., None, :]) * xs.astype(jnp.float32)[..., None]
     return a_bar, b_bar, Cm
 
@@ -114,7 +122,7 @@ def mamba_decode(p, cfg: ArchConfig, x, conv_state, ssm_state
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One-token decode. x: (b, 1, d); conv_state: (b, cw-1, di);
     ssm_state: (b, di, st)."""
-    di, st, dtr = cfg.d_inner, cfg.ssm_state, cfg.dt_rank_
+    di = cfg.d_inner
     xz = x @ p["in_proj"]
     xs, z = jnp.split(xz, [di], axis=-1)                       # (b, 1, di)
     w = p["conv_w"].astype(jnp.float32)
@@ -122,12 +130,7 @@ def mamba_decode(p, cfg: ArchConfig, x, conv_state, ssm_state
                             xs.astype(jnp.float32)], axis=1)    # (b, cw, di)
     conv = jnp.einsum("bcd,cd->bd", hist, w) + p["conv_b"]
     xs1 = jax.nn.silu(conv).astype(x.dtype)                    # (b, di)
-    proj = xs1 @ p["x_proj"]
-    dt_r, Bm, Cm = jnp.split(proj.astype(jnp.float32), [dtr, dtr + st], axis=-1)
-    dt = jax.nn.softplus(dt_r @ p["dt_proj"].astype(jnp.float32) + p["dt_bias"])
-    A = -jnp.exp(p["a_log"])
-    a_bar = jnp.exp(dt[..., None] * A)                          # (b, di, st)
-    b_bar = (dt[..., None] * Bm[:, None, :]) * xs1.astype(jnp.float32)[..., None]
+    a_bar, b_bar, Cm = _ssm_inputs(p, cfg, xs1)                 # (b, di, st)
     h = ssm_state * a_bar + b_bar
     y = jnp.einsum("bdn,bn->bd", h, Cm) + xs1.astype(jnp.float32) * p["d_skip"]
     y = (y.astype(x.dtype) * jax.nn.silu(z[:, 0]))[:, None, :]

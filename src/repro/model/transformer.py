@@ -158,11 +158,24 @@ def init_params(key, cfg: ArchConfig) -> Dict:
 # forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+def _stream(x, cfg: ArchConfig):
+    """The residual stream entering the layers: float32 where the
+    config carries it so (``residual_f32``), else as embedded."""
+    return x.astype(jnp.float32) if cfg.residual_f32 else x
+
+
+def _norm(x, gamma, cfg: ArchConfig):
+    """RMSNorm of the residual stream; a float32 stream is normed in
+    float32 and handed on in the model dtype, as the products take it."""
+    h = rmsnorm(x, gamma, cfg.norm_eps)
+    return h.astype(dtype_of(cfg.dtype)) if cfg.residual_f32 else h
+
+
 def _apply_layer(p, spec: LayerSpec, cfg: ArchConfig, x, positions,
                  memory=None, mrope_positions=None, collect: bool = False):
     aux = jnp.zeros((), jnp.float32)
     kv = None
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(x, p["ln1"], cfg)
     if spec.mixer == "attn":
         r = ATT.attention(p["mixer"], cfg, h, positions, window=spec.window,
                           mrope_positions=mrope_positions, return_kv=collect)
@@ -183,13 +196,13 @@ def _apply_layer(p, spec: LayerSpec, cfg: ArchConfig, x, positions,
     x = x + h
     if spec.cross and memory is not None:
         h = ATT.cross_attention(p["cross"], cfg,
-                                rmsnorm(x, p["ln_x"], cfg.norm_eps),
+                                _norm(x, p["ln_x"], cfg),
                                 memory, positions)
         x = x + h
     if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], rmsnorm(x, p["ln2"], cfg.norm_eps))
+        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
     elif spec.ffn == "moe":
-        h, aux = MLP.moe(p["ffn"], cfg, rmsnorm(x, p["ln2"], cfg.norm_eps))
+        h, aux = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
         x = x + h
     x = shard_activation(x, ("batch", "seq", None))
     return x, aux, kv
@@ -202,6 +215,7 @@ def _run_stack(stack, cfg: ArchConfig, role: str, x, positions,
     repeats = len(specs) // period
     aux_total = jnp.zeros((), jnp.float32)
     cache = {"slots": [], "tail": []} if collect else None
+    x = _stream(x, cfg)
     if repeats > 0:
         def body(carry, slot_params):
             xc, aux = carry
@@ -291,7 +305,7 @@ def forward(params, cfg: ArchConfig, tokens: jnp.ndarray,
 
     x, aux = _run_stack(params["decoder"], cfg, "decoder", x, positions,
                         memory, mrope_pos)
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+    x = _norm(x, params["final_ln"], cfg)
     head = params.get("lm_head", params["embed"])
     logits = unembed(x, head)
     logits = shard_activation(logits, ("batch", "seq", "vocab"))
@@ -323,7 +337,7 @@ def prefill(params, cfg: ArchConfig, tokens: jnp.ndarray,
         memory = rmsnorm(memory, params["enc_final_ln"], cfg.norm_eps)
     x, _, cache = _run_stack(params["decoder"], cfg, "decoder", x, positions,
                              memory, mrope_pos, collect=True)
-    x = rmsnorm(x[:, -1:, :], params["final_ln"], cfg.norm_eps)
+    x = _norm(x[:, -1:, :], params["final_ln"], cfg)
     head = params.get("lm_head", params["embed"])
     logits = unembed(x[:, 0, :], head)
     return logits, cache
@@ -410,7 +424,7 @@ def zero_cache_slot(cache: Dict, i) -> Dict:
 
 def _decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, cache_len,
                   memory=None, mrope_positions=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(x, p["ln1"], cfg)
     if spec.mixer == "attn":
         h, k_all, v_all = ATT.decode_attention(
             p["mixer"], cfg, h, cache["k"], cache["v"], cache_len,
@@ -425,12 +439,12 @@ def _decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, cache_len,
         b = x.shape[0]
         pos = jnp.full((b, 1), cache_len, jnp.int32)
         x = x + ATT.cross_attention(p["cross"], cfg,
-                                    rmsnorm(x, p["ln_x"], cfg.norm_eps),
+                                    _norm(x, p["ln_x"], cfg),
                                     memory, pos)
     if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], rmsnorm(x, p["ln2"], cfg.norm_eps))
+        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
     elif spec.ffn == "moe":
-        h, _ = MLP.moe(p["ffn"], cfg, rmsnorm(x, p["ln2"], cfg.norm_eps))
+        h, _ = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
         x = x + h
     return x, new_cache
 
@@ -443,7 +457,7 @@ def decode_step(params, cfg: ArchConfig, token: jnp.ndarray, cache: Dict,
     specs = layer_specs(cfg, "decoder")
     period = pattern_period(cfg, "decoder")
     repeats = len(specs) // period
-    x = embed(token, params["embed"])
+    x = _stream(embed(token, params["embed"]), cfg)
     mrope_pos = None
     if cfg.mrope:
         b = token.shape[0]
@@ -476,7 +490,7 @@ def decode_step(params, cfg: ArchConfig, token: jnp.ndarray, cache: Dict,
         x, nc = _decode_layer(p, specs[repeats * period + i], cfg, x,
                               cache["tail"][i], cache_len, memory, mrope_pos)
         new_cache["tail"].append(nc)
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+    x = _norm(x, params["final_ln"], cfg)
     head = params.get("lm_head", params["embed"])
     logits = unembed(x[:, 0, :], head)
     return logits, new_cache
@@ -487,7 +501,7 @@ def decode_step(params, cfg: ArchConfig, token: jnp.ndarray, cache: Dict,
 # ---------------------------------------------------------------------------
 
 def _chunk_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, offset):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(x, p["ln1"], cfg)
     if spec.mixer == "attn":
         h, k_rows, v_rows = ATT.chunk_attention(
             p["mixer"], cfg, h, cache["k"], cache["v"], offset,
@@ -499,9 +513,9 @@ def _chunk_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, offset):
         out = {"conv": conv, "ssm": ssm_st}
     x = x + h
     if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], rmsnorm(x, p["ln2"], cfg.norm_eps))
+        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
     elif spec.ffn == "moe":
-        h, _ = MLP.moe(p["ffn"], cfg, rmsnorm(x, p["ln2"], cfg.norm_eps))
+        h, _ = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
         x = x + h
     return x, out
 
@@ -555,6 +569,7 @@ def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn, kv_len: int,
     period = pattern_period(cfg, "decoder")
     repeats = len(specs) // period
     new_cache: Dict[str, Any] = {"slots": [], "tail": []}
+    x = _stream(x, cfg)
 
     def layer(p, spec, xc, lc):
         with jax.named_scope("layer"):
@@ -627,7 +642,7 @@ def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
         lambda p, spec, xc, lc: _chunk_layer(p, spec, cfg, xc, lc, offset),
         kv_len, offset)
     with jax.named_scope("head"):
-        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        x = _norm(x, params["final_ln"], cfg)
         head = params.get("lm_head", params["embed"])
         logits = unembed(x, head)
     return logits, new_cache
@@ -635,7 +650,7 @@ def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
 
 def _serve_decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache,
                         lengths, active):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = _norm(x, p["ln1"], cfg)
     if spec.mixer == "attn":
         h, k_row, v_row = ATT.paged_decode_attention(
             p["mixer"], cfg, h, cache["k"], cache["v"], lengths,
@@ -655,9 +670,9 @@ def _serve_decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache,
                "ssm": jnp.where(sel, ssm_st, cache["ssm"])}
     x = x + h
     if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], rmsnorm(x, p["ln2"], cfg.norm_eps))
+        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
     elif spec.ffn == "moe":
-        h, _ = MLP.moe(p["ffn"], cfg, rmsnorm(x, p["ln2"], cfg.norm_eps))
+        h, _ = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
         x = x + h
     return x, out
 
@@ -682,7 +697,7 @@ def serve_decode_step(params, cfg: ArchConfig, token: jnp.ndarray,
                                                     lengths, active),
         kv_len, lengths)
     with jax.named_scope("head"):
-        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        x = _norm(x, params["final_ln"], cfg)
         head = params.get("lm_head", params["embed"])
         logits = unembed(x[:, 0, :], head)
     return logits, new_cache
