@@ -41,6 +41,8 @@ from .scop import Scop, Statement
 VMEM_BYTES = 16 * 2**20
 LANE = 128
 SUBLANE = 8
+#: the vector register file: 64 vregs of one (SUBLANE, LANE) 32-bit tile
+VREG_FILE_BYTES = 64 * SUBLANE * LANE * 4
 
 
 @dataclass(frozen=True)
@@ -85,7 +87,8 @@ def _fit_tiles(order: List[str], dims: Dict[str, int], vector_iter: str,
                stmt: Statement,
                fixed: Optional[Dict[str, int]] = None,
                block_bytes: Optional[Callable[[Dict[str, int]], int]] = None,
-               floor: Optional[Dict[str, int]] = None) -> Dict[str, int]:
+               floor: Optional[Dict[str, int]] = None,
+               start: Optional[Dict[str, int]] = None) -> Dict[str, int]:
     """Snap tiles to TPU-friendly sizes under a VMEM budget.
 
     By default the working set comes from the shared cache model
@@ -100,15 +103,19 @@ def _fit_tiles(order: List[str], dims: Dict[str, int], vector_iter: str,
     ``fixed`` pins dims to a given tile (e.g. a VMEM-resident state dim
     that must stay whole); pinned dims are exempt from shrinking, so the
     others shrink against the true footprint.  ``floor`` gives the
-    smallest tile a dim may shrink to (default ``SUBLANE``)."""
+    smallest tile a dim may shrink to (default ``SUBLANE``); ``start``
+    the tile a dim starts from before it shrinks."""
     from .cachemodel import stmt_access_groups, working_set_bytes
 
     fixed = fixed or {}
+    start = start or {}
     tile = {}
     for it in order:
         d = dims[it]
         if it in fixed:
             tile[it] = min(fixed[it], d)
+        elif it in start:
+            tile[it] = min(start[it], d)
         elif it == vector_iter:
             tile[it] = min(d, 512 if d % 512 == 0 else LANE * max(d // LANE, 1))
             tile[it] = max(min(tile[it], d), min(d, LANE))
@@ -139,18 +146,20 @@ def scan_block_bytes(tile: Dict[str, int], fused: bool) -> int:
     :mod:`repro.kernels.scan_gate` when ``fused``) hold for a (t, d, n)
     tile: every operand counted as f32, each block's two minor dims
     padded to the (8, 128) vreg tile, pipelined blocks double-buffered,
-    plus the state scratch.  Mirrors the kernels' BlockSpecs: a/b
-    (t, n, d), c (t, n, 1), per-step rows (t, d) — o, plus x and z when
-    fused — and, fused, the (1, d) skip and the h0/h_out (n, d) blocks."""
+    plus the state scratch.  Mirrors the kernels' BlockSpecs: unfused,
+    a/b (t, n, d), c (t, n, 1) and the y rows (t, d); fused, the Δ, x, z
+    and o rows (t, d), B and C (t, n, 1), A, h0 and h_out (n, d) and the
+    (1, d) skip."""
     t, d, n = tile["t"], tile["d"], tile["n"]
 
     def blk(rows: int, cols: int, lead: int = 1) -> int:
         return (lead * -(-rows // SUBLANE) * SUBLANE
                 * -(-cols // LANE) * LANE * 4)
 
-    blocks = 2 * blk(n, d, t) + blk(n, 1, t) + blk(t, d)
     if fused:
-        blocks += 2 * blk(t, d) + blk(1, d) + 2 * blk(n, d)
+        blocks = 4 * blk(t, d) + 2 * blk(n, 1, t) + 3 * blk(n, d) + blk(1, d)
+    else:
+        blocks = 2 * blk(n, d, t) + blk(n, 1, t) + blk(t, d)
     return 2 * blocks + blk(n, d)
 
 
@@ -354,25 +363,38 @@ def _fit_scan_plan(scop: Scop, plan: KernelPlan, fused: bool) -> KernelPlan:
     (:func:`scan_block_bytes`).  The hidden state (state × d_block) is
     VMEM-resident scratch across chunks, so the state dim stays whole,
     pinned *inside* the fit so t/d shrink against the true footprint;
-    d rides the lanes, so its tile stays a whole lane width."""
+    d rides the lanes, so its tile stays a whole lane width.  In the
+    fused kernel d carries no dependence and its tile starts as wide as
+    one recurrence step's values — h, a_t, b_t and h·c_t, each a
+    (state, d) f32 tile — fit the vector registers, so the serial h
+    chain has the most independent lanes per step."""
     stmt = scop.statements[0]
     dims = _iter_extents(scop, stmt)
+    n, d = dims["n"], dims["d"]
+    start = None
+    if fused:
+        while (4 * -(-n // SUBLANE) * SUBLANE * d * 4 > VREG_FILE_BYTES
+               and d % (2 * LANE) == 0):
+            d //= 2
+        start = {"d": d}
     tile = _fit_tiles(list(plan.loop_order), dims, plan.vector_iter, stmt,
-                      fixed={"n": dims["n"]}, floor={"d": LANE},
+                      fixed={"n": n}, floor={"d": LANE}, start=start,
                       block_bytes=functools.partial(scan_block_bytes,
                                                     fused=fused))
     return replace(plan, tile=tile)
 
 
 def _scan_gate_scop(seq: int, d_inner: int, state: int) -> Scop:
-    """Fused Mamba tail: recurrence + C-contraction (3-deep) and the
+    """Fused Mamba tail: the discretised recurrence + C-contraction
+    (3-deep, reading Δ, A, B and x, not a (t, d, n) a/b) and the
     skip+gate epilogue (2-deep) share one t/d nest, so the scheduler
     sees the fusion and tiles t/d for the combined working set."""
     s = Scop("scan_gate", params={"T": seq, "D": d_inner, "S": state})
     with s.loop("t", 0, "T"):
         with s.loop("d", 0, "D"):
             with s.loop("n", 0, "S"):
-                s.stmt("H[d,n] = A[t,d,n] * H[d,n] + B[t,d,n]")
+                s.stmt("H[d,n] = exp(Dt[t,d] * Am[d,n]) * H[d,n]"
+                       " + Dt[t,d] * X[t,d] * Bs[t,n]")
                 s.stmt("Y[t,d] = Y[t,d] + H[d,n] * Cs[t,n]")
             s.stmt("O[t,d] = (Y[t,d] + X[t,d] * Dk[d]) * G[t,d]")
     return s

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..core.akg import (plan_attention, plan_matmul, plan_mamba_scan,
                         plan_scan_gate)
+from ..model.ssm import discretise
 from . import ops, ref
 
 
@@ -64,11 +65,15 @@ def run(out=sys.stdout):
     print(f"mamba_scan_128_interpret,{t_i:.1f},"
           f"chunk={plan.tile['t']} dblock={plan.tile['d']} state-in-VMEM",
           file=out)
+    dt = jax.nn.softplus(jax.random.normal(jax.random.fold_in(r, 9),
+                                           (1, 128, 256)))
+    A = -jnp.broadcast_to(jnp.arange(1, 17, dtype=jnp.float32), (256, 16))
+    bm = jax.random.normal(jax.random.fold_in(r, 4), (1, 128, 16))
     x_skip = jax.random.normal(jax.random.fold_in(r, 6), (1, 128, 256))
     dk = jax.random.normal(jax.random.fold_in(r, 7), (256,))
     z = jax.random.normal(jax.random.fold_in(r, 8), (1, 128, 256))
     plan = plan_scan_gate(128, 256, 16)
-    t_i = _time(lambda *x: ops.scan_gate(*x)[0], a_bar, b_bar, c, x_skip,
+    t_i = _time(lambda *x: ops.scan_gate(*x)[0], dt, A, bm, c, x_skip,
                 dk, z, reps=1)
     print(f"scan_gate_128_interpret,{t_i:.1f},"
           f"chunk={plan.tile['t']} dblock={plan.tile['d']} fused-gate",
@@ -131,23 +136,29 @@ def smoke(out=sys.stdout) -> int:
                                                  interpret=True),
           ref.selective_scan_ref(a_bar, b_bar, c), 1e-4)
 
-    # fused scan+skip+gate kernel (autotuned via rank_pallas_plans),
-    # full-sequence and chunked with the h0 state carry
+    # fused discretisation+scan+skip+gate kernel (autotuned via
+    # rank_pallas_plans) against the jnp route's discretisation and the
+    # fused oracle, full-sequence and chunked with the h0 state carry
+    dt = jax.nn.softplus(jax.random.normal(jax.random.fold_in(r, 9),
+                                           (bsz, s, di)) - 1.0)
+    A = -jnp.broadcast_to(jnp.arange(1, st + 1, dtype=jnp.float32), (di, st))
+    bm = jax.random.normal(jax.random.fold_in(r, 10), (bsz, s, st))
     x_skip = jax.random.normal(jax.random.fold_in(r, 6), (bsz, s, di))
     dk = jax.random.normal(jax.random.fold_in(r, 7), (di,))
     z = jax.random.normal(jax.random.fold_in(r, 8), (bsz, s, di))
     plan = plan_scan_gate(s, di, st)
     print(f"plan_scan_gate,{'>'.join(plan.loop_order)},"
           f"vec={plan.vector_iter} tiles={plan.tile}", file=out)
-    o_got, h_got = ops.scan_gate(a_bar, b_bar, c, x_skip, dk, z,
+    o_got, h_got = ops.scan_gate(dt, A, bm, c, x_skip, dk, z,
                                  interpret=True)
-    o_want, h_want = ref.scan_gate_ref(a_bar, b_bar, c, x_skip, dk, z)
+    o_want, h_want = ref.scan_gate_ref(*discretise(dt, A, bm, x_skip), c,
+                                       x_skip, dk, z)
     check("scan_gate_smoke", o_got, o_want, 1e-4)
     check("scan_gate_state_smoke", h_got, h_want, 1e-4)
     m_ = s // 2
-    _, h1 = ops.scan_gate(a_bar[:, :m_], b_bar[:, :m_], c[:, :m_],
+    _, h1 = ops.scan_gate(dt[:, :m_], A, bm[:, :m_], c[:, :m_],
                           x_skip[:, :m_], dk, z[:, :m_], interpret=True)
-    o2, h2 = ops.scan_gate(a_bar[:, m_:], b_bar[:, m_:], c[:, m_:],
+    o2, h2 = ops.scan_gate(dt[:, m_:], A, bm[:, m_:], c[:, m_:],
                            x_skip[:, m_:], dk, z[:, m_:], h0=h1,
                            interpret=True)
     check("scan_gate_chunk_carry_smoke", o2, o_want[:, m_:], 1e-4)

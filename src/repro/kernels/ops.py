@@ -51,9 +51,10 @@ def selective_scan(a_bar, b_bar, c, interpret: Optional[bool] = None):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def scan_gate(a_bar, b_bar, c, x_skip, d_skip, z, h0=None,
+def scan_gate(dt, A, B, c, x_skip, d_skip, z, h0=None,
               interpret: Optional[bool] = None):
-    """Fused selective-scan + skip + SiLU gate with state carry.
+    """Fused discretisation + selective scan + skip + SiLU gate with
+    state carry, from Δ, A, B and C.
     Returns (o (b, s, di), h_last (b, di, st))."""
-    return _sg.scan_gate(a_bar, b_bar, c, x_skip, d_skip, z, h0=h0,
+    return _sg.scan_gate(dt, A, B, c, x_skip, d_skip, z, h0=h0,
                          interpret=interpret)

@@ -1,18 +1,21 @@
 """Fused selective-scan + skip + SiLU-gate Pallas kernel (Mamba block tail).
 
-One kernel computes what the jnp path spreads over four ops:
+One kernel discretises the recurrence and computes what the jnp path
+spreads over several ops:
 
+    a_t = exp(Δ_t ⊙ A),  b_t = (Δ_t ⊙ x_t) ⊗ B_t     (discretisation)
     h_t = a_t ⊙ h_{t-1} + b_t                      (recurrence)
     y_t = h_t · c_t + x_t ⊙ d_skip                 (contraction + skip)
     o_t = y_t ⊙ silu(z_t)                          (gate)
 
-with the hidden state (state × d_block) VMEM-resident across sequence
-chunks and an explicit initial state ``h0`` — the carry that lets a
-serving engine process a prompt in chunks (continuous batching) without
-ever materializing the (b, s, d, n) hidden-state tensor in HBM between
-ops.  The final state is returned for the next chunk.  The recurrence
-and the TPU layout (d on lanes, state on sublanes, aligned row groups)
-are shared with :mod:`.mamba_scan`.
+It reads the discretisation's inputs — Δ and x (b, s, d), A (d, n), B
+and C (b, s, n) — and builds a_t/b_t in f32 in VMEM one step at a time,
+so the (b, s, d, n) coefficients never reach HBM.  The hidden state
+(state × d_block) stays VMEM-resident across sequence chunks from an
+explicit initial state ``h0`` — the carry that lets a serving engine
+process a prompt in chunks (continuous batching) — and the final state
+is returned for the next chunk.  The TPU layout (d on lanes, state on
+sublanes, aligned groups of ``GROUP`` rows) is :mod:`.mamba_scan`'s.
 
 Block geometry comes from the scheduler: ``repro.core.akg.plan_scan_gate``
 builds the fused SCoP (recurrence + gate statement in one t/d nest),
@@ -32,63 +35,81 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._mode import resolve_interpret
-from .mamba_scan import GROUP, block_geometry, scan_chunk, to_kernel_layout
+from .mamba_scan import GROUP, block_geometry
 
 
-def _kernel(a_ref, b_ref, c_ref, x_ref, dk_ref, z_ref, h0_ref,
+def _kernel(dt_ref, a_ref, b_ref, c_ref, x_ref, dk_ref, z_ref, h0_ref,
             o_ref, hout_ref, h_ref, *, chunk: int, n_chunks: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)
+        h_ref[...] = h0_ref[0]
 
-    dk = dk_ref[...].astype(jnp.float32)                 # (1, bd)
+    A = a_ref[...]                                       # (st, bd)
+    dk = dk_ref[...]                                     # (1, bd)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (GROUP, A.shape[1]), 0)
 
-    def emit(t0, y):
+    def group(g, h):
+        t0 = pl.multiple_of(g * GROUP, GROUP)
+        dt = dt_ref[0, pl.ds(t0, GROUP), :]              # (GROUP, bd)
         x = x_ref[0, pl.ds(t0, GROUP), :].astype(jnp.float32)
+        dtx = dt * x
+        y = jnp.zeros_like(dt)
+        for j in range(GROUP):
+            a = jnp.exp(dt[j:j + 1] * A)                 # (st, bd)
+            h = a * h + dtx[j:j + 1] * b_ref[0, t0 + j]  # B_t: (st, 1)
+            yj = jnp.sum(h * c_ref[0, t0 + j], axis=0, keepdims=True)
+            y = jnp.where(rows == j, yj, y)
         z = z_ref[0, pl.ds(t0, GROUP), :].astype(jnp.float32)
         o = (y + x * dk) * (z * jax.nn.sigmoid(z))
         o_ref[0, pl.ds(t0, GROUP), :] = o.astype(o_ref.dtype)
+        return h
 
-    h_ref[...] = scan_chunk(a_ref, b_ref, c_ref, h_ref[...], chunk, emit)
+    h_ref[...] = jax.lax.fori_loop(0, chunk // GROUP, group, h_ref[...])
 
     @pl.when(pl.program_id(2) == n_chunks - 1)
     def _store_state():
         hout_ref[0] = h_ref[...]
 
 
-def scan_gate(a_bar: jnp.ndarray, b_bar: jnp.ndarray, c: jnp.ndarray,
-              x_skip: jnp.ndarray, d_skip: jnp.ndarray, z: jnp.ndarray,
-              h0: Optional[jnp.ndarray] = None,
+def scan_gate(dt: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
+              c: jnp.ndarray, x_skip: jnp.ndarray, d_skip: jnp.ndarray,
+              z: jnp.ndarray, h0: Optional[jnp.ndarray] = None,
               d_block: Optional[int] = None, chunk: Optional[int] = None,
               interpret: Optional[bool] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """a_bar, b_bar: (b, s, di, st); c: (b, s, st); x_skip, z: (b, s, di);
-    d_skip: (di,); h0: (b, di, st) f32 or None (zeros).
-    Returns (o (b, s, di), h_last (b, di, st) f32)."""
-    bsz, seq, di, st = a_bar.shape
+    """dt: (b, s, di) Δ after softplus; A: (di, st) = -exp(a_log);
+    B, c: (b, s, st); x_skip, z: (b, s, di); d_skip: (di,); h0: (b, di,
+    st) or None (zeros).  Δ, A, B, c, d_skip and the state are taken in
+    f32.  Returns (o (b, s, di), h_last (b, di, st) f32)."""
+    bsz, seq, di = dt.shape
+    st = A.shape[1]
     if d_block is None or chunk is None:
         from ..core.akg import plan_scan_gate
         plan = plan_scan_gate(seq, di, st)
         d_block = d_block if d_block is not None else plan.tile["d"]
         chunk = chunk if chunk is not None else plan.tile["t"]
     seq_p, d_block, chunk = block_geometry(seq, di, d_block, chunk)
-    a, b, c4 = to_kernel_layout(a_bar, b_bar, c, seq_p - seq)
+    # padded steps have Δ = 0: a = 1, b = 0, which leave the state exact
     pad = ((0, 0), (0, seq_p - seq), (0, 0))
-    x_skip, z = jnp.pad(x_skip, pad), jnp.pad(z, pad)
+    dt, x_skip, z = (jnp.pad(v, pad) for v in
+                     (dt.astype(jnp.float32), x_skip, z))
+    B, c = (jnp.pad(v.astype(jnp.float32), pad)[..., None] for v in (B, c))
     if h0 is None:
         h0 = jnp.zeros((bsz, di, st), jnp.float32)
     h0 = jnp.swapaxes(h0.astype(jnp.float32), 1, 2)          # (b, st, di)
     n_chunks = seq_p // chunk
     grid = (bsz, di // d_block, n_chunks)
     row = pl.BlockSpec((1, chunk, d_block), lambda i, d, t: (i, t, d))
+    col = pl.BlockSpec((1, chunk, st, 1), lambda i, d, t: (i, t, 0, 0))
     state = pl.BlockSpec((1, st, d_block), lambda i, d, t: (i, 0, d))
     out, h_last = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, st, d_block), lambda i, d, t: (i, t, 0, d)),
-            pl.BlockSpec((1, chunk, st, d_block), lambda i, d, t: (i, t, 0, d)),
-            pl.BlockSpec((1, chunk, st, 1), lambda i, d, t: (i, t, 0, 0)),
+            row,
+            pl.BlockSpec((st, d_block), lambda i, d, t: (0, d)),
+            col,
+            col,
             row,
             pl.BlockSpec((1, d_block), lambda i, d, t: (0, d)),
             row,
@@ -102,5 +123,6 @@ def scan_gate(a_bar: jnp.ndarray, b_bar: jnp.ndarray, c: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((st, d_block), jnp.float32)],
         interpret=resolve_interpret(interpret),
         name="scan_gate",
-    )(a, b, c4, x_skip, d_skip.reshape(1, di), z, h0)
+    )(dt, jnp.swapaxes(A.astype(jnp.float32), 0, 1), B, c, x_skip,
+      d_skip.astype(jnp.float32).reshape(1, di), z, h0)
     return out[:, :seq], jnp.swapaxes(h_last, 1, 2)

@@ -3,9 +3,12 @@
 Sequence processing uses an associative scan over the diagonal SSM
 recurrence h_t = a_t ⊙ h_{t-1} + b_t (a_t = exp(Δ_t·A)), which is both
 TPU-friendly (log-depth) and exact. Decode keeps (conv_state, ssm_state)
-as the cache.  Prefill, chunked prefill and decode share one
-discretisation (``_ssm_inputs``, named scope ``ssm_inputs``), which holds
-Falcon-Mamba's B/C/Δ norm where the config sets ``bcdt_rms_eps``.
+as the cache.  Prefill, chunked prefill and decode share one set of
+input projections (``_ssm_inputs``, named scope ``ssm_inputs``), which
+holds Falcon-Mamba's B/C/Δ norm where the config sets ``bcdt_rms_eps``.
+The fused Pallas route hands Δ, A, B and C to ``scan_gate``, which
+discretises inside the kernel; the jnp routes discretise with
+``discretise``.
 """
 from __future__ import annotations
 
@@ -46,8 +49,8 @@ def _ssm_scan(a, b):
 
 @jax.named_scope("ssm_inputs")
 def _ssm_inputs(p, cfg: ArchConfig, xs):
-    """Input-dependent recurrence coefficients from post-conv
-    activations xs (..., di): (a_bar, b_bar (..., di, st), Cm (..., st)).
+    """Input-dependent recurrence inputs from post-conv activations xs
+    (..., di), all f32: (Δ (..., di), A (di, st), Bm, Cm (..., st)).
     With ``cfg.bcdt_rms_eps`` set, Δ's low-rank input, B and C each go
     through a weight-free RMSNorm first (Falcon-Mamba)."""
     st, dtr = cfg.ssm_state, cfg.dt_rank_
@@ -58,9 +61,15 @@ def _ssm_inputs(p, cfg: ArchConfig, xs):
                           for v in (dt_r, Bm, Cm))
     dt = jax.nn.softplus(dt_r @ p["dt_proj"].astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["a_log"])                                    # (di, st)
-    a_bar = jnp.exp(dt[..., None] * A)                          # (..., di, st)
+    return dt, A, Bm, Cm
+
+
+def discretise(dt, A, Bm, xs):
+    """The recurrence's coefficients a_bar = exp(Δ·A) and b_bar = Δ·B·x,
+    each (..., di, st) f32, from ``_ssm_inputs``' Δ, A and B and xs."""
+    a_bar = jnp.exp(dt[..., None] * A)
     b_bar = (dt[..., None] * Bm[..., None, :]) * xs.astype(jnp.float32)[..., None]
-    return a_bar, b_bar, Cm
+    return a_bar, b_bar
 
 
 def _fused_scan_gate(cfg: ArchConfig, xs) -> bool:
@@ -72,8 +81,8 @@ def _fused_scan_gate(cfg: ArchConfig, xs) -> bool:
 
 def _selective_ssm(p, cfg: ArchConfig, xs, return_last: bool = False):
     """xs: (b, s, di) post-conv activations; returns ((b, s, di), h_last)."""
-    a_bar, b_bar, Cm = _ssm_inputs(p, cfg, xs)
-    _, h = _ssm_scan(a_bar, b_bar)                              # (b, s, di, st)
+    dt, A, Bm, Cm = _ssm_inputs(p, cfg, xs)
+    _, h = _ssm_scan(*discretise(dt, A, Bm, xs))                # (b, s, di, st)
     y = jnp.einsum("bsdn,bsn->bsd", h, Cm)
     y = y + xs.astype(jnp.float32) * p["d_skip"]
     return y.astype(xs.dtype), (h[:, -1] if return_last else None)
@@ -95,8 +104,8 @@ def mamba(p, cfg: ArchConfig, x, return_state: bool = False):
     xs = jax.nn.silu(conv + p["conv_b"]).astype(x.dtype)
     if _fused_scan_gate(cfg, xs):
         from ..kernels import ops
-        a_bar, b_bar, Cm = _ssm_inputs(p, cfg, xs)
-        y, h_full = ops.scan_gate(a_bar, b_bar, Cm, xs, p["d_skip"], z)
+        dt, A, Bm, Cm = _ssm_inputs(p, cfg, xs)
+        y, h_full = ops.scan_gate(dt, A, Bm, Cm, xs, p["d_skip"], z)
         h_last = h_full if return_state else None
     else:
         y, h_last = _selective_ssm(p, cfg, xs, return_last=return_state)
@@ -130,7 +139,8 @@ def mamba_decode(p, cfg: ArchConfig, x, conv_state, ssm_state
                             xs.astype(jnp.float32)], axis=1)    # (b, cw, di)
     conv = jnp.einsum("bcd,cd->bd", hist, w) + p["conv_b"]
     xs1 = jax.nn.silu(conv).astype(x.dtype)                    # (b, di)
-    a_bar, b_bar, Cm = _ssm_inputs(p, cfg, xs1)                 # (b, di, st)
+    dt, A, Bm, Cm = _ssm_inputs(p, cfg, xs1)
+    a_bar, b_bar = discretise(dt, A, Bm, xs1)                  # (b, di, st)
     h = ssm_state * a_bar + b_bar
     y = jnp.einsum("bdn,bn->bd", h, Cm) + xs1.astype(jnp.float32) * p["d_skip"]
     y = (y.astype(x.dtype) * jax.nn.silu(z[:, 0]))[:, None, :]
@@ -159,13 +169,13 @@ def mamba_chunk(p, cfg: ArchConfig, x, conv_state, ssm_state
     conv = sum(hist[:, i:i + c, :] * w[i] for i in range(cw))
     new_conv = pre[:, -(cw - 1):, :] if cw > 1 else conv_state
     xs = jax.nn.silu(conv + p["conv_b"]).astype(x.dtype)
-    a_bar, b_bar, Cm = _ssm_inputs(p, cfg, xs)
+    dt, A, Bm, Cm = _ssm_inputs(p, cfg, xs)
     if _fused_scan_gate(cfg, xs):
         from ..kernels import ops
-        y, h_last = ops.scan_gate(a_bar, b_bar, Cm, xs, p["d_skip"], z,
+        y, h_last = ops.scan_gate(dt, A, Bm, Cm, xs, p["d_skip"], z,
                                   h0=ssm_state)
     else:
-        cum_a, h = _ssm_scan(a_bar, b_bar)
+        cum_a, h = _ssm_scan(*discretise(dt, A, Bm, xs))
         h = h + cum_a * ssm_state.astype(jnp.float32)[:, None]
         y = jnp.einsum("bsdn,bsn->bsd", h, Cm)
         y = (y + xs.astype(jnp.float32) * p["d_skip"]).astype(xs.dtype)

@@ -68,16 +68,19 @@ def test_planned_matmul_compiles_at_granite_widths(spec, m, k, n):
 
 @pytest.mark.parametrize("seq", [256, 44])
 def test_scan_gate_compiles_at_falcon_mamba_widths(spec, seq):
-    """Fused scan+gate over a prefill chunk at d_inner 8192, state 16,
-    f32, with the state carry; 44 rows is a ragged last chunk."""
-    f32 = jnp.float32
+    """Fused discretisation + scan + gate over a prefill chunk at d_inner
+    8192, state 16: Δ, A, B, C and the state in f32, x and z in bf16 as
+    the model hands them over, with the state carry; 44 rows is a ragged
+    last chunk."""
+    f32, bf = jnp.float32, jnp.bfloat16
     di, st = 8192, 16
     text = _mosaic_text(
-        lambda a, b, c, x, d, z, h: sg.scan_gate(a, b, c, x, d, z, h0=h,
-                                                 interpret=False),
-        spec((1, seq, di, st), f32), spec((1, seq, di, st), f32),
-        spec((1, seq, st), f32), spec((1, seq, di), f32), spec((di,), f32),
-        spec((1, seq, di), f32), spec((1, di, st), f32))
+        lambda dt, A, B, C, x, d, z, h: sg.scan_gate(
+            dt, A, B, C, x, d, z, h0=h, interpret=False),
+        spec((1, seq, di), f32), spec((di, st), f32),
+        spec((1, seq, st), f32), spec((1, seq, st), f32),
+        spec((1, seq, di), bf), spec((di,), f32), spec((1, seq, di), bf),
+        spec((1, di, st), f32))
     assert "tpu_custom_call" in text
 
 

@@ -62,6 +62,68 @@ def test_selective_scan_allclose(b, s, di, st):
                                rtol=1e-4, atol=1e-4)
 
 
+def _ssm_operands(b, s, di, st, seed=3):
+    """scan_gate's operands as the model hands them over: Δ after
+    softplus, A = -exp(a_log) over Mamba's 1..st init, B, C, x, d_skip,
+    z and an initial state."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    dt = jax.nn.softplus(jax.random.normal(k[0], (b, s, di)) - 1.0)
+    A = -jnp.broadcast_to(jnp.arange(1, st + 1, dtype=jnp.float32), (di, st))
+    B = jax.random.normal(k[1], (b, s, st))
+    C = jax.random.normal(k[2], (b, s, st))
+    x = jax.random.normal(k[3], (b, s, di))
+    dk = jax.random.normal(k[4], (di,))
+    z = jax.random.normal(k[5], (b, s, di))
+    h0 = jax.random.normal(k[6], (b, di, st))
+    return dt, A, B, C, x, dk, z, h0
+
+
+def _scan_gate_want(dt, A, B, C, x, dk, z, h0=None):
+    """The jnp route: discretise to a_bar/b_bar, then the fused oracle."""
+    from repro.model.ssm import discretise
+    return ref.scan_gate_ref(*discretise(dt, A, B, x), C, x, dk, z, h0=h0)
+
+
+@pytest.mark.parametrize("b,s,di,st", [(2, 64, 256, 16), (1, 128, 128, 8),
+                                       (1, 44, 128, 16)])
+@pytest.mark.parametrize("with_h0", [False, True])
+def test_scan_gate_discretises_like_the_jnp_route(b, s, di, st, with_h0):
+    """The kernel builds a_t = exp(Δ·A) and b_t = Δ·x·B itself; 44 rows
+    pad to a GROUP multiple with Δ = 0 steps, which leave h exact."""
+    dt, A, B, C, x, dk, z, h0 = _ssm_operands(b, s, di, st)
+    h0 = h0 if with_h0 else None
+    o, h = ops.scan_gate(dt, A, B, C, x, dk, z, h0=h0, interpret=True)
+    o_want, h_want = _scan_gate_want(dt, A, B, C, x, dk, z, h0)
+    assert o.shape == x.shape and h.shape == (b, di, st)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_want),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_want),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("chunk", [None, 16])
+def test_scan_gate_two_chunk_carry_matches_one_pass(chunk):
+    """Two calls carrying h_last into h0 give the one-pass output and
+    final state; ``chunk`` 16 also carries the state across the
+    kernel's own sequence grid."""
+    from repro.kernels import scan_gate as sg
+    dt, A, B, C, x, dk, z, h0 = _ssm_operands(2, 96, 256, 16, seed=4)
+    o_want, h_want = _scan_gate_want(dt, A, B, C, x, dk, z, h0)
+    m = 40
+    halves = [tuple(v[:, sl] for v in (dt, B, C, x, z))
+              for sl in (slice(None, m), slice(m, None))]
+    h = h0
+    outs = []
+    for dt_, B_, C_, x_, z_ in halves:
+        o, h = sg.scan_gate(dt_, A, B_, C_, x_, dk, z_, h0=h, chunk=chunk,
+                            interpret=True)
+        outs.append(o)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, 1)),
+                               np.asarray(o_want), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_want),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_attention_plan_lanes():
     plan = plan_attention(512, 512, 128)
     assert plan.vector_iter == "d"           # head_dim on lanes
@@ -140,6 +202,67 @@ def test_mamba_plan_tpu_legal(seq, di, st):
     assert scan_block_bytes(plan.tile, fused=False) <= VMEM_BYTES, plan
     assert scan_block_bytes(plan_scan_gate(seq, di, st).tile,
                             fused=True) <= VMEM_BYTES
+
+
+@pytest.mark.parametrize("seq,st,d_tile", [(256, 16, 1024), (44, 16, 1024),
+                                           (256, 8, 2048)])
+def test_scan_gate_plan_fills_the_vector_registers(seq, st, d_tile):
+    """At d_inner 8192 the fused plan's d tile is the widest whose
+    recurrence step (h, a_t, b_t and h·c_t, (state, d) f32 each) fits
+    the vector registers: at state 16 that is 1024 lanes, the fastest
+    of d_block 128 to 4096 timed on a TPU v5e."""
+    from repro.core.akg import VREG_FILE_BYTES
+    tile = plan_scan_gate(seq, 8192, st).tile
+    assert tile == {"d": d_tile, "t": min(seq, 128), "n": st}
+    step = 4 * max(st, SUBLANE) * d_tile * 4
+    assert step <= VREG_FILE_BYTES < 2 * step
+    assert scan_block_bytes(tile, fused=True) <= VMEM_BYTES
+
+
+def _pallas_vmem_bytes(fn, *args) -> int:
+    """VMEM the one pallas_call in ``fn`` holds, read from its own block
+    mappings: each block's two minor dims padded to the (8, 128) vreg
+    tile and counted as f32, double-buffered, plus the scratch."""
+    import math
+    eqn = next(e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+               if e.primitive.name == "pallas_call")
+    gm = eqn.params["grid_mapping"]
+
+    def padded(shape):
+        *lead, rows, cols = shape
+        return (math.prod(lead) * -(-rows // SUBLANE) * SUBLANE
+                * -(-cols // LANE) * LANE * 4)
+
+    return (2 * sum(padded(bm.block_aval.shape) for bm in gm.block_mappings)
+            + sum(padded(a.shape) for a in gm.scratch_avals))
+
+
+@pytest.mark.parametrize("chunk,d_block", [(128, 128), (256, 512),
+                                           (48, 256)])
+def test_scan_block_bytes_mirrors_the_kernels_blockspecs(chunk, d_block):
+    """The planner's footprint is the kernels' own: scan_gate's Δ, x, z,
+    o rows, B/C columns, A, h0/h_out and skip blocks; selective_scan's
+    a/b blocks, c columns and y rows."""
+    from repro.kernels import mamba_scan as ms
+    from repro.kernels import scan_gate as sg
+    f32 = jnp.float32
+    b, di, st = 1, 1024, 16
+    tile = {"t": chunk, "d": d_block, "n": st}
+    rows, cols = (jax.ShapeDtypeStruct((b, chunk, n), f32)
+                  for n in (di, st))
+    fused = _pallas_vmem_bytes(
+        lambda dt, A, B, C, x, dk, z: sg.scan_gate(
+            dt, A, B, C, x, dk, z, d_block=d_block, chunk=chunk,
+            interpret=True),
+        rows, jax.ShapeDtypeStruct((di, st), f32), cols, cols, rows,
+        jax.ShapeDtypeStruct((di,), f32), rows)
+    assert scan_block_bytes(tile, fused=True) == fused
+    ab = jax.ShapeDtypeStruct((b, chunk, di, st), f32)
+    plain = _pallas_vmem_bytes(
+        lambda a, bb, c: ms.selective_scan(a, bb, c, d_block=d_block,
+                                           chunk=chunk, interpret=True),
+        ab, ab, cols)
+    assert scan_block_bytes(tile, fused=False) == plain
 
 
 def test_mamba_kernel_consumes_scheduler_plan():

@@ -186,3 +186,37 @@ def test_residual_stream_dtype_between_layers(cfg, stream):
     logits, _ = jax.jit(T.serve_decode_step, static_argnums=(1, 6))(
         p, cfg, tok, cache, lens, act, 32)
     assert logits.dtype == jnp.dtype(cfg.dtype)
+
+
+@pytest.mark.parametrize("rows", [32, 44])
+def test_mamba_chunk_fused_and_jnp_routes_agree(rows):
+    """The fused route (Δ, A, B, C into ``scan_gate``, discretised in
+    the kernel) and the jnp route (``discretise`` then the associative
+    scan) give one chunk's output, conv tail and carried state, from a
+    carried state, with Falcon-Mamba's B/C/Δ norm on."""
+    from repro.model import pallas_mode
+    assert FALCON.bcdt_rms_eps
+    p = jax.tree.map(lambda v: v[0],
+                     params(FALCON)["decoder"]["slots"][0]["mixer"])
+    k = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(k[0], (2, rows, FALCON.d_model)).astype(jnp.bfloat16)
+    conv = jax.random.normal(
+        k[1], (2, FALCON.conv_width - 1, FALCON.d_inner)).astype(jnp.bfloat16)
+    h0 = jax.random.normal(k[2], (2, FALCON.d_inner, FALCON.ssm_state))
+
+    def route(enabled):
+        with pallas_mode.pallas_mode(enabled=enabled, min_scan_seq=32):
+            f = functools.partial(SSM.mamba_chunk, p, FALCON)
+            return (str(jax.make_jaxpr(f)(x, conv, h0)),
+                    jax.jit(f)(x, conv, h0))
+
+    (fused_text, fused), (plain_text, plain) = route(True), route(False)
+    assert "pallas_call" in fused_text and "pallas_call" not in plain_text
+    assert np.array_equal(np.asarray(fused[1], np.float32),
+                          np.asarray(plain[1], np.float32))
+    np.testing.assert_allclose(np.asarray(fused[2]), np.asarray(plain[2]),
+                               rtol=1e-4, atol=1e-4)
+    # within the bf16 rounding of the output (a zero h0 moves it by ~1.5)
+    out_f, out_p = (np.asarray(o, np.float32) for o in (fused[0], plain[0]))
+    np.testing.assert_allclose(out_f, out_p, rtol=0.02,
+                               atol=0.02 * np.abs(out_p).max())
