@@ -58,11 +58,9 @@ SPEC: List[Tuple[str, str, str, float]] = [
     ("BENCH_loadgen_tcp.json", "tcp_over_unix_distinct", "higher", 0.15),
     ("BENCH_loadgen_tcp.json", "errors_total", "lower", 0.0),
     ("BENCH_loadgen_tcp.json", "shared_computed_tcp", "lower", 0.0),
-    # serving engine: continuous-batching Pallas path vs the alternating
-    # jnp loop, both timed in the same run — the speedup ratio and the
-    # greedy-token identity bit are host-portable; paged_memory_ratio is
-    # a structural byte count (full KV bytes / paged KV bytes)
-    ("BENCH_serve.json", "speedup_tokens_per_s", "higher", 0.30),
+    # serving engine: the greedy-token identity bit against the
+    # prefill + decode_step reference; paged_memory_ratio is a
+    # structural byte count (full KV bytes / paged KV bytes)
     ("BENCH_serve.json", "tokens_identical", "higher", 0.0),
     ("BENCH_serve.json", "paged_memory_ratio", "higher", 0.05),
 ]

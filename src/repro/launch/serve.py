@@ -3,28 +3,23 @@ kernels.
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite_3_2b \
         [--batch 4 --prompt-len 256 --gen 32 --chunk 256 --max-len N] \
-        [--seed 0] [--engine continuous] [--pallas] [--smoke]
+        [--seed 0] [--pallas] [--smoke]
 
 Without ``--smoke`` the arch is served at its published widths, with
 random weights made from ``--seed``.
 
-Two engines share the model's step functions:
-
-* :class:`ServeEngine` — the legacy alternating loop: whole-prompt
-  prefill into a slot, then lock-step decode of every active slot with a
-  shared ``max(lengths)`` cache length.  Kept as the baseline the bench
-  compares against (and because the dry-run lowers its step functions).
-* :class:`ContinuousEngine` — finer-grained continuous batching:
-  per-request admission into free slots, prompt prefill in fixed-size
-  chunks interleaved with decode ticks (a long prompt never stalls
-  in-flight decodes), ragged per-slot cache lengths, and paged KV — the
-  decode tick reads only the page-aligned used prefix of the cache, page
-  size from ``plan_attention``'s k tile (a stack with no attention layer
-  reads no KV and takes one bound, ``max_len``, for every tick, so each
-  tick kind compiles once).  One host sync per tick.  With
-  ``use_pallas=True`` the model layers route through the Pallas kernels
-  (flash attention with the SMEM q-offset for prefill chunks, the fused
-  scan+gate kernel for Mamba archs) — see :mod:`repro.model.pallas_mode`.
+:class:`ContinuousEngine` serves on the model's step functions
+(``chunk_step`` and ``serve_decode_step``): per-request admission into
+free slots, prompt prefill in fixed-size chunks interleaved with decode
+ticks (a long prompt never stalls in-flight decodes), ragged per-slot
+cache lengths, and paged KV — the decode tick reads only the
+page-aligned used prefix of the cache, page size from
+``plan_attention``'s k tile (a stack with no attention layer reads no
+KV and takes one bound, ``max_len``, for every tick, so each tick kind
+compiles once).  One host sync per tick.  With ``use_pallas=True`` the
+model layers route through the Pallas kernels (flash attention with the
+SMEM q-offset for prefill chunks, the fused scan+gate kernel for Mamba
+archs) — see :mod:`repro.model.pallas_mode`.
 """
 from __future__ import annotations
 
@@ -53,73 +48,6 @@ class Request:
     t_admit: float = 0.0           # taken into a slot
     t_first: float = 0.0           # first generated token (prefill done)
     token_times: List[float] = field(default_factory=list)
-
-
-def _merge_slot(cache: Dict, pre: Dict, slot) -> Dict:
-    """Write a b=1 prefill cache into batch slot ``slot`` structurally:
-    "slots" entries carry batch on axis 1, "tail" entries on axis 0 (a
-    fact of init_cache's layout — not a shape heuristic; matching on
-    sizes silently skipped mismatched leaves and left stale rows)."""
-    def wr(axis):
-        def go(dst, src):
-            starts = [0] * dst.ndim
-            starts[axis] = slot
-            return jax.lax.dynamic_update_slice(dst, src.astype(dst.dtype),
-                                                starts)
-        return go
-    with jax.named_scope("kv_cache"):
-        return {"slots": [jax.tree.map(wr(1), c, sc)
-                          for c, sc in zip(cache["slots"], pre["slots"])],
-                "tail": [jax.tree.map(wr(0), c, sc)
-                         for c, sc in zip(cache["tail"], pre["tail"])]}
-
-
-class ServeEngine:
-    """Fixed-batch decode engine with greedy sampling (alternating
-    prefill/decode baseline)."""
-
-    def __init__(self, cfg, params, batch: int, max_len: int):
-        self.cfg, self.params = cfg, params
-        self.batch, self.max_len = batch, max_len
-        self.cache = T.init_cache(cfg, batch, max_len)
-        self.tokens = jnp.zeros((batch, 1), jnp.int32)
-        self.lengths = [0] * batch
-        self.slots: List[Optional[Request]] = [None] * batch
-        self._decode = jax.jit(
-            lambda p, t, c, n: T.decode_step(p, cfg, t, c, n))
-        self._prefill = jax.jit(lambda p, t: T.prefill(p, cfg, t))
-        self._merge = jax.jit(
-            lambda c, pre, s: _merge_slot(T.zero_cache_slot(c, s), pre, s))
-
-    def admit(self, req: Request, slot: int):
-        logits, pre = self._prefill(self.params, req.prompt)
-        # zero the slot's rows first (reused-slot hygiene: a shorter new
-        # prompt must not expose the previous occupant's KV rows through
-        # the shared max(lengths) decode mask), then merge structurally.
-        self.cache = self._merge(self.cache, pre, jnp.int32(slot))
-        self.slots[slot] = req
-        self.lengths[slot] = req.prompt.shape[1]
-        nxt = int(jnp.argmax(logits[0]))
-        req.generated.append(nxt)
-        self.tokens = self.tokens.at[slot, 0].set(nxt)
-
-    def reset(self):
-        """Back to the post-init state, keeping compiled step functions."""
-        self.cache = jax.tree.map(jnp.zeros_like, self.cache)
-        self.tokens = jnp.zeros((self.batch, 1), jnp.int32)
-        self.lengths = [0] * self.batch
-        self.slots = [None] * self.batch
-
-    def step(self):
-        n = max(self.lengths)
-        logits, self.cache = self._decode(self.params, self.tokens,
-                                          self.cache, jnp.int32(n))
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        self.tokens = nxt[:, None]
-        for i, req in enumerate(self.slots):
-            if req is not None and not req.done:
-                req.generated.append(int(nxt[i]))
-                self.lengths[i] += 1
 
 
 FREE, PREFILL, DECODE = 0, 1, 2
@@ -534,8 +462,6 @@ def main(argv=None) -> int:
     ap.add_argument("--max-len", type=int, default=0,
                     help="KV rows per slot (default: prompt-len + gen + 1)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--engine", choices=("alternating", "continuous"),
-                    default="continuous")
     ap.add_argument("--chunk", type=int, default=256)
     ap.add_argument("--pallas", action="store_true")
     ap.add_argument("--smoke", action="store_true",
@@ -556,30 +482,20 @@ def main(argv=None) -> int:
                                   (1, args.prompt_len), 2, cfg.vocab)
                for i in range(args.batch)]
     t0 = time.time()
-    if args.engine == "alternating":
-        eng = ServeEngine(cfg, params, args.batch, max_len)
-        for i, prompt in enumerate(prompts):
-            eng.admit(Request(i, prompt), slot=i)
-        for _ in range(args.gen - 1):
-            eng.step()
-        reqs = [r for r in eng.slots if r is not None]
-        jax.block_until_ready(eng.cache)
-    else:
-        ceng = ContinuousEngine(cfg, params, args.batch, max_len,
-                                chunk=args.chunk, use_pallas=args.pallas,
-                                max_new=args.gen)
-        reqs = [Request(i, p) for i, p in enumerate(prompts)]
-        for r in reqs:
-            ceng.submit(r)
-        ceng.run()
-        jax.block_until_ready(ceng.cache)
-        print(f"overlap ratio: {ceng.overlap_ratio():.2f}, "
-              f"page={ceng.page}")
+    eng = ContinuousEngine(cfg, params, args.batch, max_len,
+                           chunk=args.chunk, use_pallas=args.pallas,
+                           max_new=args.gen)
+    reqs = [Request(i, p) for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    jax.block_until_ready(eng.cache)
+    print(f"overlap ratio: {eng.overlap_ratio():.2f}, page={eng.page}")
     dt = time.time() - t0
     ntok = sum(len(r.generated) for r in reqs)
     dev = jax.devices()
     print(f"{len(reqs)} seqs, {ntok} tokens in {dt:.2f}s host wall time, "
-          f"compiles included ({cfg.name}, {args.engine}, "
+          f"compiles included ({cfg.name}, "
           f"{dev[0].platform} {dev[0].device_kind} x{len(dev)})")
     for req in reqs:
         print(f"req{req.rid}: {req.generated[:10]}")

@@ -8,7 +8,7 @@ from ``repro.core.akg`` plans — wherever the operand shapes clear the
 per-kernel thresholds below.  Everything stays a pure function of the
 same inputs, so a jit retrace picks the mode up and numerical parity
 against the jnp path is a plain ``allclose`` (asserted by
-``tests/test_serve.py`` and the serving engine's startup parity check).
+``tests/test_serve.py``).
 
 The thresholds are the same on every backend.  They were tuned on the
 CPU, where the kernels run in the Pallas interpreter: the

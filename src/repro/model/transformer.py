@@ -155,7 +155,7 @@ def init_params(key, cfg: ArchConfig) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# forward (train / prefill)
+# one layer, one walk, one head
 # ---------------------------------------------------------------------------
 
 def _stream(x, cfg: ArchConfig):
@@ -171,88 +171,127 @@ def _norm(x, gamma, cfg: ArchConfig):
     return h.astype(dtype_of(cfg.dtype)) if cfg.residual_f32 else h
 
 
-def _apply_layer(p, spec: LayerSpec, cfg: ArchConfig, x, positions,
-                 memory=None, mrope_positions=None, collect: bool = False):
-    aux = jnp.zeros((), jnp.float32)
-    kv = None
-    h = _norm(x, p["ln1"], cfg)
-    if spec.mixer == "attn":
-        r = ATT.attention(p["mixer"], cfg, h, positions, window=spec.window,
-                          mrope_positions=mrope_positions, return_kv=collect)
-        if collect:
-            h, (k, v) = r
-            kv = {"k": k, "v": v}
-        else:
-            h = r
-    elif spec.mixer == "enc_attn":
-        h = ATT.attention_noncausal(p["mixer"], cfg, h, positions)
-    else:
-        r = SSM.mamba(p["mixer"], cfg, h, return_state=collect)
-        if collect:
-            h, (conv_st, ssm_st) = r
-            kv = {"conv": conv_st, "ssm": ssm_st}
-        else:
-            h = r
-    x = x + h
-    if spec.cross and memory is not None:
-        h = ATT.cross_attention(p["cross"], cfg,
-                                _norm(x, p["ln_x"], cfg),
-                                memory, positions)
+def _block(p, spec: LayerSpec, cfg: ArchConfig, x, mix, memory=None,
+           cross_pos=None):
+    """One layer: norm → mixer → residual → cross-attention (where the
+    layer has it and ``memory`` is given, at ``cross_pos``) → MLP or MoE
+    FFN, in the ``layer`` named scope.
+
+    ``mix(p_mixer, h) -> (h, out)`` is the calling path's own mixer:
+    the attention or SSM call, and what it hands back (``out``: K/V or
+    states).  Returns (x, aux, out); ``aux`` is the MoE balance loss."""
+    with jax.named_scope("layer"):
+        aux = jnp.zeros((), jnp.float32)
+        h, out = mix(p["mixer"], _norm(x, p["ln1"], cfg))
         x = x + h
-    if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
-    elif spec.ffn == "moe":
-        h, aux = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
-        x = x + h
-    x = shard_activation(x, ("batch", "seq", None))
-    return x, aux, kv
+        if spec.cross and memory is not None:
+            x = x + ATT.cross_attention(p["cross"], cfg,
+                                        _norm(x, p["ln_x"], cfg),
+                                        memory, cross_pos)
+        if spec.ffn == "mlp":
+            x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
+        elif spec.ffn == "moe":
+            h, aux = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
+            x = x + h
+        return x, aux, out
 
 
-def _run_stack(stack, cfg: ArchConfig, role: str, x, positions,
-               memory=None, mrope_positions=None, collect: bool = False):
+def _walk(stack, cfg: ArchConfig, role: str, x, layer, xs=None, wrap=None,
+          scanned=None):
+    """Run ``layer`` over a stack: the pattern period over the stacked
+    repeats by ``lax.scan`` (a Python loop under ``UNROLL``), then the
+    tail layers, on the residual stream as :func:`_stream` makes it.
+
+    ``layer(p, spec, x, lx, at) -> (x, y)``.  ``p`` is the layer's
+    params, a stacked layer's slice re-constrained by
+    ``gather_params_for_compute``; ``lx`` its entry of ``xs``
+    (``{"slots": [...], "tail": [...]}`` in the stack's layout, the
+    stacked entries scanned beside their params; None without ``xs``);
+    ``at`` is ``("slots", s, r)``, ``r`` the repeat index (traced in the
+    scan), or ``("tail", i, None)``, for what a layer reads by index.
+    Returns (x, the ``y``s in the same layout, stacked over repeats).
+    ``wrap`` wraps the scan body (activation checkpointing);
+    ``scanned(ys)`` maps the stacked layers' ``y``s as soon as the scan
+    ends, before the tail layers run.  The scan and what it does for
+    itself — slicing each layer's weights, stacking the ``y``s — is the
+    ``kv_cache`` named scope."""
     specs = layer_specs(cfg, role)
     period = pattern_period(cfg, role)
     repeats = len(specs) // period
-    aux_total = jnp.zeros((), jnp.float32)
-    cache = {"slots": [], "tail": []} if collect else None
+    xs = xs or {"slots": [None] * period, "tail": [None] * len(stack["tail"])}
+    ys: Dict[str, List] = {"slots": [], "tail": []}
     x = _stream(x, cfg)
-    if repeats > 0:
-        def body(carry, slot_params):
-            xc, aux = carry
-            kvs = []
+    if repeats:
+        def body(xc, xs_r):
+            r, slot_params, lxs = xs_r
+            outs = []
             for s in range(period):
-                p_s = gather_params_for_compute(slot_params[s])
-                xc, a, kv = _apply_layer(p_s, specs[s], cfg, xc,
-                                         positions, memory, mrope_positions,
-                                         collect)
-                aux = aux + a
-                kvs.append(kv)
-            return (xc, aux), (tuple(kvs) if collect else None)
-        body_ck = _maybe_remat(body)
-        if UNROLL:
-            ys_list = []
-            carry = (x, aux_total)
-            for r in range(repeats):
-                carry, y = body_ck(carry, jax.tree.map(lambda v: v[r],
-                                                       tuple(stack["slots"])))
-                ys_list.append(y)
-            (x, aux_total) = carry
-            ys = (jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
-                  if collect else None)
-        else:
-            (x, aux_total), ys = jax.lax.scan(body_ck, (x, aux_total),
-                                              tuple(stack["slots"]))
-        if collect:
-            cache["slots"] = list(ys)
+                xc, y = layer(gather_params_for_compute(slot_params[s]),
+                              specs[s], xc, lxs[s], ("slots", s, r))
+                outs.append(y)
+            return xc, tuple(outs)
+        body = wrap(body) if wrap else body
+        scan_xs = (jnp.arange(repeats), tuple(stack["slots"]),
+                   tuple(xs["slots"]))
+        with jax.named_scope("kv_cache"):
+            if UNROLL:
+                ys_list = []
+                for r in range(repeats):
+                    x, y = body(x, jax.tree.map(lambda v: v[r], scan_xs))
+                    ys_list.append(y)
+                outs = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
+            else:
+                x, outs = jax.lax.scan(body, x, scan_xs)
+        ys["slots"] = scanned(list(outs)) if scanned else list(outs)
     for i, p in enumerate(stack["tail"]):
-        x, a, kv = _apply_layer(p, specs[repeats * period + i], cfg, x,
-                                positions, memory, mrope_positions, collect)
-        aux_total = aux_total + a
-        if collect:
-            cache["tail"].append(kv)
+        x, y = layer(p, specs[repeats * period + i], x, xs["tail"][i],
+                     ("tail", i, None))
+        ys["tail"].append(y)
+    return x, ys
+
+
+def _head(params, cfg: ArchConfig, x, pos=None):
+    """The final norm, then the logits: of every position, or of
+    position ``pos`` alone, (b, vocab)."""
+    with jax.named_scope("head"):
+        x = _norm(x, params["final_ln"], cfg)
+        if pos is not None:
+            x = x[:, pos, :]
+        return unembed(x, params.get("lm_head", params["embed"]))
+
+
+# ---------------------------------------------------------------------------
+# forward (train / prefill)
+# ---------------------------------------------------------------------------
+
+def _run_stack(stack, cfg: ArchConfig, role: str, x, positions,
+               memory=None, mrope_positions=None, collect: bool = False):
+    """The whole-sequence walk: returns (x, aux) and, with ``collect``,
+    the decode cache the layers' K/V and final states make."""
+    def layer(p, spec, x, _, at):
+        def mix(pm, h):
+            if spec.mixer == "enc_attn":
+                return ATT.attention_noncausal(pm, cfg, h, positions), None
+            if spec.mixer == "attn":
+                res = ATT.attention(pm, cfg, h, positions, window=spec.window,
+                                    mrope_positions=mrope_positions,
+                                    return_kv=collect)
+                names = ("k", "v")
+            else:
+                res = SSM.mamba(pm, cfg, h, return_state=collect)
+                names = ("conv", "ssm")
+            if not collect:
+                return res, None
+            h, state = res
+            return h, dict(zip(names, state))
+        x, aux, out = _block(p, spec, cfg, x, mix, memory, positions)
+        return shard_activation(x, ("batch", "seq", None)), (aux, out)
+
+    x, ys = _walk(stack, cfg, role, x, layer, wrap=_maybe_remat)
+    aux = sum(jnp.sum(a) for a, _ in ys["slots"] + ys["tail"])
     if collect:
-        return x, aux_total, cache
-    return x, aux_total
+        return x, aux, {g: [c for _, c in ys[g]] for g in ("slots", "tail")}
+    return x, aux
 
 
 def _frontend_embeds(params, cfg: ArchConfig, stub: jnp.ndarray) -> jnp.ndarray:
@@ -273,26 +312,19 @@ def _mrope_positions(cfg: ArchConfig, batch: int, seq: int):
     return jnp.broadcast_to(pos[None], (batch, seq, 3)).astype(jnp.int32)
 
 
-def forward(params, cfg: ArchConfig, tokens: jnp.ndarray,
-            frontend: Optional[jnp.ndarray] = None,
-            enc_frontend: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-sequence forward. Returns (logits, aux_loss).
-
-    tokens: (b, s_text). For frontend archs, ``frontend`` (b, fl, d) is
-    prepended (vlm) ; for enc-dec, ``enc_frontend`` feeds the encoder.
-    """
+def _inputs(params, cfg: ArchConfig, tokens, frontend, enc_frontend):
+    """What the decoder stack takes in ``forward`` and ``prefill``: the
+    embedded tokens (behind the frontend's embeddings for a VLM), their
+    positions and M-RoPE positions, and the encoder's memory."""
     x = embed(tokens, params["embed"])
     b = tokens.shape[0]
-    mrope_pos = None
-    if cfg.frontend_stub and cfg.family in ("vlm",) and frontend is not None:
-        fe = _frontend_embeds(params, cfg, frontend)
-        x = jnp.concatenate([fe, x], axis=1)
+    if cfg.frontend_stub and cfg.family == "vlm" and frontend is not None:
+        x = jnp.concatenate([_frontend_embeds(params, cfg, frontend), x],
+                            axis=1)
     seq = x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(seq)[None], (b, seq))
-    if cfg.mrope:
-        mrope_pos = _mrope_positions(cfg, b, seq)
+    mrope_pos = _mrope_positions(cfg, b, seq) if cfg.mrope else None
     x = shard_activation(x, ("batch", "seq", None))
-
     memory = None
     if cfg.enc_layers:
         enc_in = _frontend_embeds(params, cfg, enc_frontend)
@@ -302,13 +334,23 @@ def forward(params, cfg: ArchConfig, tokens: jnp.ndarray,
                                shard_activation(enc_in, ("batch", "seq", None)),
                                epos)
         memory = rmsnorm(memory, params["enc_final_ln"], cfg.norm_eps)
+    return x, positions, mrope_pos, memory
 
+
+def forward(params, cfg: ArchConfig, tokens: jnp.ndarray,
+            frontend: Optional[jnp.ndarray] = None,
+            enc_frontend: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Full-sequence forward. Returns (logits, aux_loss).
+
+    tokens: (b, s_text). For frontend archs, ``frontend`` (b, fl, d) is
+    prepended (vlm) ; for enc-dec, ``enc_frontend`` feeds the encoder.
+    """
+    x, positions, mrope_pos, memory = _inputs(params, cfg, tokens, frontend,
+                                              enc_frontend)
     x, aux = _run_stack(params["decoder"], cfg, "decoder", x, positions,
                         memory, mrope_pos)
-    x = _norm(x, params["final_ln"], cfg)
-    head = params.get("lm_head", params["embed"])
-    logits = unembed(x, head)
-    logits = shard_activation(logits, ("batch", "seq", "vocab"))
+    logits = shard_activation(_head(params, cfg, x),
+                              ("batch", "seq", "vocab"))
     return logits, aux
 
 
@@ -318,29 +360,11 @@ def prefill(params, cfg: ArchConfig, tokens: jnp.ndarray,
             ) -> Tuple[jnp.ndarray, Dict]:
     """Prefill: full forward that also materializes the decode cache.
     Returns (last-position logits (b, vocab), cache)."""
-    x = embed(tokens, params["embed"])
-    b = tokens.shape[0]
-    mrope_pos = None
-    if cfg.frontend_stub and cfg.family == "vlm" and frontend is not None:
-        x = jnp.concatenate([_frontend_embeds(params, cfg, frontend), x], axis=1)
-    seq = x.shape[1]
-    positions = jnp.broadcast_to(jnp.arange(seq)[None], (b, seq))
-    if cfg.mrope:
-        mrope_pos = _mrope_positions(cfg, b, seq)
-    x = shard_activation(x, ("batch", "seq", None))
-    memory = None
-    if cfg.enc_layers:
-        enc_in = _frontend_embeds(params, cfg, enc_frontend)
-        epos = jnp.broadcast_to(jnp.arange(enc_in.shape[1])[None],
-                                (b, enc_in.shape[1]))
-        memory, _ = _run_stack(params["encoder"], cfg, "encoder", enc_in, epos)
-        memory = rmsnorm(memory, params["enc_final_ln"], cfg.norm_eps)
+    x, positions, mrope_pos, memory = _inputs(params, cfg, tokens, frontend,
+                                              enc_frontend)
     x, _, cache = _run_stack(params["decoder"], cfg, "decoder", x, positions,
                              memory, mrope_pos, collect=True)
-    x = _norm(x[:, -1:, :], params["final_ln"], cfg)
-    head = params.get("lm_head", params["embed"])
-    logits = unembed(x[:, 0, :], head)
-    return logits, cache
+    return _head(params, cfg, x[:, -1:, :], 0), cache
 
 
 # ---------------------------------------------------------------------------
@@ -376,9 +400,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Dict:
 
 # In a cache built by init_cache, "slots" entries are stacked over
 # layer-repeats so batch is axis 1; "tail" entries are per-layer so
-# batch is axis 0.  The helpers below use that structural fact (not a
-# shape heuristic — matching on sizes is exactly the ``bdim is None``
-# bug the serving engine used to have).
+# batch is axis 0.  The helpers below use that structural fact, not a
+# shape heuristic.
 
 def _slot_axis_map(cache, fn_slots, fn_tail):
     return {"slots": [jax.tree.map(fn_slots, c) for c in cache["slots"]],
@@ -422,103 +445,40 @@ def zero_cache_slot(cache: Dict, i) -> Dict:
         return _slot_axis_map(cache, z(1), z(0))
 
 
-def _decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, cache_len,
-                  memory=None, mrope_positions=None):
-    h = _norm(x, p["ln1"], cfg)
-    if spec.mixer == "attn":
-        h, k_all, v_all = ATT.decode_attention(
-            p["mixer"], cfg, h, cache["k"], cache["v"], cache_len,
-            window=spec.window, mrope_positions=mrope_positions)
-        new_cache = {"k": k_all, "v": v_all}
-    else:
-        h, conv, ssm_st = SSM.mamba_decode(p["mixer"], cfg, h,
-                                           cache["conv"], cache["ssm"])
-        new_cache = {"conv": conv, "ssm": ssm_st}
-    x = x + h
-    if spec.cross and memory is not None:
-        b = x.shape[0]
-        pos = jnp.full((b, 1), cache_len, jnp.int32)
-        x = x + ATT.cross_attention(p["cross"], cfg,
-                                    _norm(x, p["ln_x"], cfg),
-                                    memory, pos)
-    if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
-    elif spec.ffn == "moe":
-        h, _ = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
-        x = x + h
-    return x, new_cache
-
-
 def decode_step(params, cfg: ArchConfig, token: jnp.ndarray, cache: Dict,
                 cache_len: jnp.ndarray, memory: Optional[jnp.ndarray] = None
                 ) -> Tuple[jnp.ndarray, Dict]:
-    """One decode step. token: (b, 1) int32; returns (logits (b, vocab),
-    new cache)."""
-    specs = layer_specs(cfg, "decoder")
-    period = pattern_period(cfg, "decoder")
-    repeats = len(specs) // period
-    x = _stream(embed(token, params["embed"]), cfg)
+    """One decode step over whole caches, every slot at ``cache_len``:
+    the reference the serving steps are checked against.
+    token: (b, 1) int32; returns (logits (b, vocab), new cache)."""
+    b = token.shape[0]
     mrope_pos = None
     if cfg.mrope:
-        b = token.shape[0]
-        base = _mrope_positions(cfg, b, 1)
-        mrope_pos = base + cache_len.astype(jnp.int32)
-    new_cache: Dict[str, Any] = {"slots": [], "tail": []}
-    if repeats:
-        def body(carry, xs):
-            xc = carry
-            slot_params, slot_caches = xs
-            new_slots = []
-            for s in range(period):
-                p_s = gather_params_for_compute(slot_params[s])
-                xc, nc = _decode_layer(p_s, specs[s], cfg, xc,
-                                       slot_caches[s], cache_len, memory,
-                                       mrope_pos)
-                new_slots.append(nc)
-            return xc, tuple(new_slots)
-        scan_xs = (tuple(params["decoder"]["slots"]), tuple(cache["slots"]))
-        if UNROLL:
-            ys_list = []
-            for r in range(repeats):
-                x, y = body(x, jax.tree.map(lambda v: v[r], scan_xs))
-                ys_list.append(y)
-            new_slots = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
-        else:
-            x, new_slots = jax.lax.scan(body, x, scan_xs)
-        new_cache["slots"] = list(new_slots)
-    for i, p in enumerate(params["decoder"]["tail"]):
-        x, nc = _decode_layer(p, specs[repeats * period + i], cfg, x,
-                              cache["tail"][i], cache_len, memory, mrope_pos)
-        new_cache["tail"].append(nc)
-    x = _norm(x, params["final_ln"], cfg)
-    head = params.get("lm_head", params["embed"])
-    logits = unembed(x[:, 0, :], head)
-    return logits, new_cache
+        mrope_pos = _mrope_positions(cfg, b, 1) + cache_len.astype(jnp.int32)
+    cross_pos = (jnp.full((b, 1), cache_len, jnp.int32)
+                 if memory is not None else None)
+
+    def layer(p, spec, x, c, at):
+        def mix(pm, h):
+            if spec.mixer == "attn":
+                h, k, v = ATT.decode_attention(
+                    pm, cfg, h, c["k"], c["v"], cache_len,
+                    window=spec.window, mrope_positions=mrope_pos)
+                return h, {"k": k, "v": v}
+            h, conv, ssm_st = SSM.mamba_decode(pm, cfg, h, c["conv"],
+                                               c["ssm"])
+            return h, {"conv": conv, "ssm": ssm_st}
+        x, _, out = _block(p, spec, cfg, x, mix, memory, cross_pos)
+        return x, out
+
+    x, new_cache = _walk(params["decoder"], cfg, "decoder",
+                         embed(token, params["embed"]), layer, cache)
+    return _head(params, cfg, x, 0), new_cache
 
 
 # ---------------------------------------------------------------------------
 # serving fast path: chunked prefill + ragged paged decode
 # ---------------------------------------------------------------------------
-
-def _chunk_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache, offset):
-    h = _norm(x, p["ln1"], cfg)
-    if spec.mixer == "attn":
-        h, k_rows, v_rows = ATT.chunk_attention(
-            p["mixer"], cfg, h, cache["k"], cache["v"], offset,
-            window=spec.window)
-        out = {"k": k_rows, "v": v_rows}
-    else:
-        h, conv, ssm_st = SSM.mamba_chunk(p["mixer"], cfg, h,
-                                          cache["conv"], cache["ssm"])
-        out = {"conv": conv, "ssm": ssm_st}
-    x = x + h
-    if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
-    elif spec.ffn == "moe":
-        h, _ = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
-        x = x + h
-    return x, out
-
 
 def _kv_prefix(v, layer, kv_len: int):
     """Rows ``[:kv_len]`` of one layer's K or V: of layer ``layer`` (a
@@ -545,13 +505,12 @@ def _put_rows(v, rows, starts):
     return v
 
 
-def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn, kv_len: int,
-                starts):
-    """Shared slot-scan + tail walk for the serving step functions:
-    ``layer_fn(p, spec, x, layer_cache) -> (x, out)``.
+def _stack_walk(params, cfg: ArchConfig, x, cache, mix, kv_len: int, starts):
+    """The serving steps' walk: :func:`_walk` with ``mix(spec, p_mixer,
+    h, layer_cache) -> (h, out)`` as each layer's mixer.
 
     An attention layer is handed its K/V prefix ``[:, :kv_len]`` and
-    returns only the K/V rows it produced; the walk writes them once, in
+    returns only the K/V rows it produced; they are written once, in
     place, at row ``starts`` (:func:`_put_rows`) — after the scan for
     the stacked layers, after the layer for a tail layer.  The stacked
     K/V are a loop-invariant operand of the scan, each layer reading its
@@ -561,20 +520,10 @@ def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn, kv_len: int,
     the scan's ``xs``/``ys``.
 
     Named scopes: each layer's own operations are ``layer`` (with the
-    layer functions' ``attention``/``ssm``/``mlp``/``moe`` inside); the
-    walk's own — the scan that slices each layer's weights, the prefix
-    reads, the SSM states' restacking and the row writes — are
+    mixers' ``attention``/``ssm`` and the FFN's ``mlp``/``moe`` inside);
+    the walk's own — the scan that slices each layer's weights, the
+    prefix reads, the SSM states' restacking and the row writes — are
     ``kv_cache``."""
-    specs = layer_specs(cfg, "decoder")
-    period = pattern_period(cfg, "decoder")
-    repeats = len(specs) // period
-    new_cache: Dict[str, Any] = {"slots": [], "tail": []}
-    x = _stream(x, cfg)
-
-    def layer(p, spec, xc, lc):
-        with jax.named_scope("layer"):
-            return layer_fn(p, spec, xc, lc)
-
     def prefix(c, r):
         with jax.named_scope("kv_cache"):
             return jax.tree.map(lambda v: _kv_prefix(v, r, kv_len), c)
@@ -583,44 +532,28 @@ def _stack_walk(params, cfg: ArchConfig, x, cache, layer_fn, kv_len: int,
         with jax.named_scope("kv_cache"):
             return jax.tree.map(lambda v, n: _put_rows(v, n, starts), c, rows)
 
-    if repeats:
-        kv = [specs[s].mixer == "attn" for s in range(period)]
-        slots = cache["slots"]
+    def layer(p, spec, xc, lc, at):
+        group, j, r = at
+        attn = spec.mixer == "attn"
+        if attn:
+            lc = prefix(cache[group][j], r)
+        xc, _, out = _block(p, spec, cfg, xc,
+                            lambda pm, h: mix(spec, pm, h, lc))
+        if attn and r is None:
+            out = put(cache[group][j], out)
+        return xc, out
 
-        def body(carry, xs):
-            xc = carry
-            r, slot_params, states = xs
-            outs = []
-            for s in range(period):
-                p_s = gather_params_for_compute(slot_params[s])
-                lc = prefix(slots[s], r) if kv[s] else states[s]
-                xc, out = layer(p_s, specs[s], xc, lc)
-                outs.append(out)
-            return xc, tuple(outs)
-        states = tuple(None if kv[s] else slots[s] for s in range(period))
-        scan_xs = (jnp.arange(repeats), tuple(params["decoder"]["slots"]),
-                   states)
-        with jax.named_scope("kv_cache"):
-            if UNROLL:
-                ys_list = []
-                for r in range(repeats):
-                    x, y = body(x, jax.tree.map(lambda v: v[r], scan_xs))
-                    ys_list.append(y)
-                outs = jax.tree.map(lambda *vs: jnp.stack(vs), *ys_list)
-            else:
-                x, outs = jax.lax.scan(body, x, scan_xs)
-        new_cache["slots"] = [put(slots[s], outs[s]) if kv[s] else outs[s]
-                              for s in range(period)]
-    for i, p in enumerate(params["decoder"]["tail"]):
-        spec = specs[repeats * period + i]
-        c = cache["tail"][i]
-        if spec.mixer == "attn":
-            x, rows = layer(p, spec, x, prefix(c, None))
-            c = put(c, rows)
-        else:
-            x, c = layer(p, spec, x, c)
-        new_cache["tail"].append(c)
-    return x, new_cache
+    def states(group):
+        # what goes through the scan: an SSM layer's states, no K/V
+        return [None if "k" in c else c for c in cache[group]]
+
+    def put_scanned(ys):
+        return [put(c, y) if "k" in c else y
+                for c, y in zip(cache["slots"], ys)]
+
+    return _walk(params["decoder"], cfg, "decoder", x, layer,
+                 {"slots": states("slots"), "tail": states("tail")},
+                 scanned=put_scanned)
 
 
 def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
@@ -634,47 +567,19 @@ def chunk_step(params, cfg: ArchConfig, tokens: jnp.ndarray, cache: Dict,
     Returns (logits (b, c, vocab) for *every* chunk position — the
     caller picks the last real one to seed decoding — and the cache with
     the chunk's K/V rows and the SSM states updated)."""
+    def mix(spec, pm, h, c):
+        if spec.mixer == "attn":
+            h, k_rows, v_rows = ATT.chunk_attention(
+                pm, cfg, h, c["k"], c["v"], offset, window=spec.window)
+            return h, {"k": k_rows, "v": v_rows}
+        h, conv, ssm_st = SSM.mamba_chunk(pm, cfg, h, c["conv"], c["ssm"])
+        return h, {"conv": conv, "ssm": ssm_st}
+
     with jax.named_scope("embed"):
         x = embed(tokens, params["embed"])
     x = shard_activation(x, ("batch", "seq", None))
-    x, new_cache = _stack_walk(
-        params, cfg, x, cache,
-        lambda p, spec, xc, lc: _chunk_layer(p, spec, cfg, xc, lc, offset),
-        kv_len, offset)
-    with jax.named_scope("head"):
-        x = _norm(x, params["final_ln"], cfg)
-        head = params.get("lm_head", params["embed"])
-        logits = unembed(x, head)
-    return logits, new_cache
-
-
-def _serve_decode_layer(p, spec: LayerSpec, cfg: ArchConfig, x, cache,
-                        lengths, active):
-    h = _norm(x, p["ln1"], cfg)
-    if spec.mixer == "attn":
-        h, k_row, v_row = ATT.paged_decode_attention(
-            p["mixer"], cfg, h, cache["k"], cache["v"], lengths,
-            window=spec.window)
-        # inactive slots (mid-prefill / retired) write at their own
-        # lengths[i] — a row the next prefill chunk or admission zeroing
-        # overwrites, so no select is needed on the KV pages
-        out = {"k": k_row, "v": v_row}
-    else:
-        h, conv, ssm_st = SSM.mamba_decode(p["mixer"], cfg, h,
-                                           cache["conv"], cache["ssm"])
-        # the recurrent states are the *carry* of an in-flight prefill:
-        # a garbage decode update would corrupt the next chunk, so keep
-        # inactive slots' states untouched
-        sel = active[:, None, None]
-        out = {"conv": jnp.where(sel, conv, cache["conv"]),
-               "ssm": jnp.where(sel, ssm_st, cache["ssm"])}
-    x = x + h
-    if spec.ffn == "mlp":
-        x = x + MLP.mlp(p["ffn"], _norm(x, p["ln2"], cfg))
-    elif spec.ffn == "moe":
-        h, _ = MLP.moe(p["ffn"], cfg, _norm(x, p["ln2"], cfg))
-        x = x + h
-    return x, out
+    x, new_cache = _stack_walk(params, cfg, x, cache, mix, kv_len, offset)
+    return _head(params, cfg, x), new_cache
 
 
 def serve_decode_step(params, cfg: ArchConfig, token: jnp.ndarray,
@@ -689,18 +594,26 @@ def serve_decode_step(params, cfg: ArchConfig, token: jnp.ndarray,
     kv_len: static page-aligned bound ≥ max(lengths)+1.  Returns
     (logits (b, vocab), the cache with one K/V row per slot written at
     ``lengths`` and the active slots' SSM states updated)."""
+    def mix(spec, pm, h, c):
+        if spec.mixer == "attn":
+            h, k_row, v_row = ATT.paged_decode_attention(
+                pm, cfg, h, c["k"], c["v"], lengths, window=spec.window)
+            # inactive slots (mid-prefill / retired) write at their own
+            # lengths[i] — a row the next prefill chunk or admission
+            # zeroing overwrites, so no select is needed on the KV pages
+            return h, {"k": k_row, "v": v_row}
+        h, conv, ssm_st = SSM.mamba_decode(pm, cfg, h, c["conv"], c["ssm"])
+        # the recurrent states are the *carry* of an in-flight prefill:
+        # a garbage decode update would corrupt the next chunk, so keep
+        # inactive slots' states untouched
+        sel = active[:, None, None]
+        return h, {"conv": jnp.where(sel, conv, c["conv"]),
+                   "ssm": jnp.where(sel, ssm_st, c["ssm"])}
+
     with jax.named_scope("embed"):
         x = embed(token, params["embed"])
-    x, new_cache = _stack_walk(
-        params, cfg, x, cache,
-        lambda p, spec, xc, lc: _serve_decode_layer(p, spec, cfg, xc, lc,
-                                                    lengths, active),
-        kv_len, lengths)
-    with jax.named_scope("head"):
-        x = _norm(x, params["final_ln"], cfg)
-        head = params.get("lm_head", params["embed"])
-        logits = unembed(x[:, 0, :], head)
-    return logits, new_cache
+    x, new_cache = _stack_walk(params, cfg, x, cache, mix, kv_len, lengths)
+    return _head(params, cfg, x, 0), new_cache
 
 
 # ---------------------------------------------------------------------------

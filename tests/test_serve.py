@@ -1,11 +1,12 @@
 """Serving-engine tests: slot-reuse hygiene, admission ordering,
 ragged-prefill interleave determinism, and Pallas-vs-jnp parity.
 
-The engines sample greedily, so every property here is asserted as
+The engine samples greedily, so every property here is asserted as
 bit-identical token sequences — not allclose.  The reference for a
-request is always the same request run in isolation (batch-1 prefill +
-decode loop): continuous batching, chunked prefill, paged KV, and the
-Pallas kernels must not change a single argmax.
+request is always the same request run in isolation (:func:`solo_greedy`:
+batch-1 ``prefill`` of its prompt, then a ``decode_step`` loop):
+continuous batching, chunked prefill, paged KV, and the Pallas kernels
+must not change a single argmax.
 """
 from __future__ import annotations
 
@@ -14,36 +15,54 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs.registry import get_arch
-from repro.launch.serve import (ContinuousEngine, Request, ServeEngine,
-                                _merge_slot)
+from repro.launch.serve import ContinuousEngine, Request
 from repro.model import pallas_mode
 from repro.model import transformer as T
 
-CFG = get_arch("granite_3_2b").smoke()
+# dense GQA, and pure Mamba (no K/V: conv and SSM states only)
+CONFIGS = {name: get_arch(name).smoke()
+           for name in ("granite_3_2b", "falcon_mamba_7b")}
+CFG = CONFIGS["granite_3_2b"]
 
 
-@functools.lru_cache(maxsize=1)
-def params():
-    return T.init_params(jax.random.PRNGKey(0), CFG)
+@functools.lru_cache(maxsize=None)
+def params(name: str = "granite_3_2b"):
+    return T.init_params(jax.random.PRNGKey(0), CONFIGS[name])
 
 
-def prompt(seed: int, plen: int):
+def prompt(seed: int, plen: int, vocab: int = CFG.vocab):
     return jax.random.randint(jax.random.fold_in(jax.random.PRNGKey(7),
                                                  seed),
-                              (1, plen), 2, CFG.vocab)
+                              (1, plen), 2, vocab)
 
 
-def solo_greedy(pr, gen: int, max_len: int):
-    """Reference: the request alone in a batch-1 alternating engine."""
-    eng = ServeEngine(CFG, params(), 1, max_len)
-    req = Request(0, pr)
-    eng.admit(req, slot=0)
-    for _ in range(gen - 1):
-        eng.step()
-    return req.generated
+@functools.lru_cache(maxsize=None)
+def reference_steps(name: str):
+    cfg = CONFIGS[name]
+    return (jax.jit(lambda p, t: T.prefill(p, cfg, t)),
+            jax.jit(lambda p, t, c, n: T.decode_step(p, cfg, t, c, n)))
+
+
+def solo_greedy(pr, gen: int, max_len: int, name: str = "granite_3_2b"):
+    """Reference: the request alone at batch 1 — ``prefill`` of its
+    prompt into the first rows of a fresh cache, then a greedy
+    ``decode_step`` loop."""
+    prefill, step = reference_steps(name)
+    logits, pre = prefill(params(name), pr)
+    cache = jax.tree.map(
+        lambda c, v: jax.lax.dynamic_update_slice(c, v.astype(c.dtype),
+                                                  (0,) * c.ndim),
+        T.init_cache(CONFIGS[name], 1, max_len), pre)
+    toks = [int(jnp.argmax(logits[0]))]
+    for n in range(pr.shape[1], pr.shape[1] + gen - 1):
+        logits, cache = step(params(name), jnp.asarray([[toks[-1]]]),
+                             cache, jnp.int32(n))
+        toks.append(int(jnp.argmax(logits[0])))
+    return toks
 
 
 def run_continuous(prompts, gen, max_len, batch, **kw):
@@ -55,50 +74,48 @@ def run_continuous(prompts, gen, max_len, batch, **kw):
     return eng, reqs
 
 
+def slot_rows(cache, i):
+    """Batch slot ``i`` of every cache leaf, on the host ("slots"
+    entries carry batch on axis 1, "tail" entries on axis 0)."""
+    return {"slots": [jax.tree.map(lambda v: np.asarray(v[:, i]), c)
+                      for c in cache["slots"]],
+            "tail": [jax.tree.map(lambda v: np.asarray(v[i]), c)
+                     for c in cache["tail"]]}
+
+
 # ---------------------------------------------------------------------------
-# ServeEngine slot reuse (the admit cache-merge regression)
+# slot reuse
 # ---------------------------------------------------------------------------
 
-def test_admit_slot_reuse_zeroes_stale_rows():
-    """Two sequential requests through one slot: the second must see a
-    slot wiped of the first occupant's KV rows.  The old shape-heuristic
-    merge (`bdim is None` silent skip) left request A's decode rows in
-    the gap between B's prompt and the shared max(lengths) mask, which
-    B then attended."""
-    plen, j, k, max_len = 8, 4, 4, 32
-    eng = ServeEngine(CFG, params(), 2, max_len)
-    a, long_req = Request(0, prompt(1, plen)), Request(1, prompt(2, plen))
-    eng.admit(a, slot=0)
-    eng.admit(long_req, slot=1)
-    for _ in range(j):
-        eng.step()           # A's decode writes rows [plen, plen+j)
-    a.done = True
-    b = Request(2, prompt(3, plen))
-    eng.admit(b, slot=0)     # reuse: must zero slot 0 first
+@pytest.mark.parametrize("name", CONFIGS)
+def test_admit_slot_reuse_zeroes_stale_rows(name):
+    """A request admitted into a slot a finished request used finds it
+    wiped: every K/V row, conv tail and SSM state of that slot is zero,
+    the other slot's in-flight rows are untouched, and the new request
+    then generates its solo reference tokens."""
+    cfg, plen, max_len = CONFIGS[name], 8, 32
+    eng = ContinuousEngine(cfg, params(name), 2, max_len, chunk=8,
+                           max_new=8)
+    a = Request(0, prompt(1, plen, cfg.vocab), max_new=3)
+    busy = Request(1, prompt(2, plen, cfg.vocab), max_new=8)
+    for r in (a, busy):
+        eng.submit(r)
+    while not a.done:
+        eng.tick()
+    assert not busy.done
+    before = slot_rows(eng.cache, 1)
+    assert any(np.any(v) for v in jax.tree.leaves(slot_rows(eng.cache, 0)))
 
-    # structural check: every slot-0 cache row past B's prompt is zero,
-    # while slot 1 still holds its occupant's rows there
-    for entry in eng.cache["slots"]:
-        kc = entry["k"]      # (repeats, batch, S, hkv, hd)
-        assert not jnp.any(kc[:, 0, plen:])
-        assert jnp.any(kc[:, 1, plen:plen + j])
+    b = Request(2, prompt(3, plen, cfg.vocab), max_new=4)
+    eng.submit(b)
+    eng._admit_free_slots()
+    assert eng.slots[0] is b
+    assert not any(np.any(v) for v in jax.tree.leaves(slot_rows(eng.cache, 0)))
+    jax.tree.map(np.testing.assert_array_equal, slot_rows(eng.cache, 1),
+                 before)
 
-    for _ in range(k):
-        eng.step()
-
-    # bit-identical reference: B prefilled into a fresh slot, decoding
-    # behind the same shared mask trajectory (slot 1 is j tokens ahead,
-    # so B attends j zero rows it never wrote — same as in the engine)
-    logits, pre = jax.jit(lambda p, t: T.prefill(p, CFG, t))(params(),
-                                                             b.prompt)
-    cache = _merge_slot(T.init_cache(CFG, 1, max_len), pre, 0)
-    toks = [int(jnp.argmax(logits[0]))]
-    step = jax.jit(lambda p, t, c, n: T.decode_step(p, CFG, t, c, n))
-    for t in range(k):
-        lg, cache = step(params(), jnp.asarray([[toks[-1]]], jnp.int32),
-                         cache, jnp.int32(plen + j + t))
-        toks.append(int(jnp.argmax(lg[0])))
-    assert b.generated == toks
+    eng.run()
+    assert b.generated == solo_greedy(b.prompt, 4, max_len, name)
 
 
 # ---------------------------------------------------------------------------
@@ -161,21 +178,15 @@ def test_pallas_parity_bit_identical():
         [r.generated for r in jnp_reqs]
 
 
-def test_continuous_matches_alternating():
-    """Equal-length batch: the continuous engine and the alternating
-    baseline agree token for token (the bench's identity gate)."""
+def test_equal_length_batch_matches_solo():
+    """Equal-length batch: each request's tokens from the continuous
+    engine equal its solo reference token for token (the bench's
+    identity gate)."""
     gen, max_len, plen, batch = 6, 48, 16, 3
     prompts = [prompt(30 + i, plen) for i in range(batch)]
-    base = ServeEngine(CFG, params(), batch, max_len)
-    base_reqs = [Request(i, p) for i, p in enumerate(prompts)]
-    for i, r in enumerate(base_reqs):
-        base.admit(r, slot=i)
-    for _ in range(gen - 1):
-        base.step()
-    _, cont_reqs = run_continuous(prompts, gen, max_len, batch=batch,
-                                  chunk=8)
-    assert [r.generated for r in cont_reqs] == \
-        [r.generated for r in base_reqs]
+    _, reqs = run_continuous(prompts, gen, max_len, batch=batch, chunk=8)
+    for r in reqs:
+        assert r.generated == solo_greedy(r.prompt, gen, max_len)
 
 
 def test_submit_validation():

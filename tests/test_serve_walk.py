@@ -3,7 +3,7 @@
 ``serve_decode_step`` and ``chunk_step`` hand each attention layer its
 K/V prefix and write the rows it returns in place, after the layer scan;
 SSM layers still replace their whole state.  These tests hold that walk
-to the legacy paths bit for bit (``decode_step`` for decode, ``prefill``
+to the reference paths bit for bit (``decode_step`` for decode, ``prefill``
 for a chunk), check that nothing but the new rows changes in the
 returned cache, and check the decode tick's program structure: no layer
 scan output spans the cache's rows, and the compiled program needs less
@@ -59,7 +59,7 @@ def is_kv(path) -> bool:
 
 
 def prefix_of(cache, kv_len):
-    """The cache cut to its first ``kv_len`` rows: the legacy steps then
+    """The cache cut to its first ``kv_len`` rows: the reference steps then
     attend over the same rows as the paged ones."""
     return jax.tree_util.tree_map_with_path(
         lambda path, v: jax.lax.slice_in_dim(v, 0, kv_len,
@@ -79,9 +79,9 @@ def assert_same_except(new, old, written):
 @pytest.mark.parametrize("name", CONFIGS)
 def test_serve_decode_step_matches_legacy_decode(name):
     """Ragged lengths and a mixed active mask: every slot's logits are
-    bit-identical to the legacy ``decode_step`` of that slot alone, each
+    bit-identical to the reference ``decode_step`` of that slot alone, each
     slot's K/V row lands at its own length in every layer (inactive
-    slots too, as before) with the legacy's values, inactive slots' SSM
+    slots too, as before) with the reference's values, inactive slots' SSM
     states are untouched, and nothing else in the cache moves."""
     cfg, p = CONFIGS[name], params(name)
     cache = filled_cache(cfg, 3)
@@ -90,10 +90,10 @@ def test_serve_decode_step_matches_legacy_decode(name):
     tok = jax.random.randint(jax.random.PRNGKey(2), (3, 1), 2, cfg.vocab)
     logits, new = jax.jit(T.serve_decode_step, static_argnums=(1, 6))(
         p, cfg, tok, cache, lengths, active, KV)
-    legacy = jax.jit(T.decode_step, static_argnums=(1,))
+    ref_step = jax.jit(T.decode_step, static_argnums=(1,))
     for i in range(3):
         old = prefix_of(T.cache_slot_view(cache, i), KV)
-        lg, ref = legacy(p, cfg, tok[i:i + 1], old, lengths[i])
+        lg, ref = ref_step(p, cfg, tok[i:i + 1], old, lengths[i])
         assert jnp.array_equal(logits[i], lg[0]), f"slot {i}"
         got = prefix_of(T.cache_slot_view(new, i), KV)
 
@@ -101,7 +101,7 @@ def test_serve_decode_step_matches_legacy_decode(name):
             if is_kv(path):
                 return jnp.array_equal(g, r)
             # an inactive slot keeps its SSM states; an active slot's are
-            # not held to the one-slot legacy run, whose float32 update
+            # not held to the one-slot reference run, whose float32 update
             # rounds differently from a batch of three in the last bit
             return bool(active[i]) or jnp.array_equal(g, o)
         assert all(jax.tree.leaves(jax.tree_util.tree_map_with_path(
@@ -144,7 +144,7 @@ def test_chunk_step_writes_only_its_rows(name):
 
 def test_chunk_step_matches_legacy_prefill():
     """Two prefill chunks, the second at a nonzero offset, end in the
-    logits and K/V rows of the legacy whole-prompt ``prefill``."""
+    logits and K/V rows of the reference whole-prompt ``prefill``."""
     cfg, p = CONFIGS["granite_3_2b"], params("granite_3_2b")
     plen, off = 24, 16
     toks = jax.random.randint(jax.random.PRNGKey(4), (1, plen), 2, cfg.vocab)

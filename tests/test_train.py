@@ -181,18 +181,20 @@ def test_adamw_converges_quadratic():
 
 def test_serve_engine_smoke():
     import jax
-    from repro.launch.serve import Request, ServeEngine
+    from repro.launch.serve import ContinuousEngine, Request
     from repro.model import transformer as T
     cfg = get_arch("granite_3_2b").smoke()
     key = jax.random.PRNGKey(0)
     params = T.init_params(key, cfg)
-    eng = ServeEngine(cfg, params, batch=2, max_len=24)
-    for i in range(2):
-        prompt = jax.random.randint(jax.random.fold_in(key, i),
-                                    (1, 8), 2, cfg.vocab)
-        eng.admit(Request(i, prompt), slot=i)
-    for _ in range(4):
-        eng.step()
-    for req in eng.slots:
+    eng = ContinuousEngine(cfg, params, batch=2, max_len=24, chunk=8,
+                           max_new=5)
+    reqs = [Request(i, jax.random.randint(jax.random.fold_in(key, i),
+                                          (1, 8), 2, cfg.vocab))
+            for i in range(2)]
+    for req in reqs:
+        eng.submit(req)
+    eng.run()
+    for req in reqs:
+        assert req.done
         assert len(req.generated) == 5
         assert all(0 <= t < cfg.vocab for t in req.generated)
