@@ -13,8 +13,9 @@ consumed by the Pallas kernels in ``repro.kernels``.
 maps to a plan.  ``plan_matmul`` / ``plan_attention`` /
 ``plan_mamba_scan`` are thin wrappers that build the operator SCoP,
 schedule it (through the structural schedule cache, tree included in the
-payload) and lower — plus at most a kernel-specific tile clamp (flash
-attention's online-softmax state, the mamba VMEM-resident hidden state).
+payload) and lower — plus at most a kernel-specific tile fit (flash
+attention's online-softmax state, the mamba VMEM-resident hidden state,
+the matmul's HBM traffic and grid steps).
 
 TPU adaptation: the vector iterator maps to the 128-lane VPU axis, the
 next-inner to 8 sublanes; tiles snap to LANE/SUBLANE multiples; tile
@@ -32,6 +33,7 @@ import functools
 from dataclasses import dataclass, replace
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ..launch.roofline import HBM_BW, PEAK_FLOPS
 from .config import tensor_style
 from .resilience import provenance as _provenance, schedule_with_ladder
 from .schedcache import global_cache
@@ -293,10 +295,17 @@ def _plan_memo(name: str):
 def plan_matmul(m: int, n: int, k: int,
                 strategy: str = "tensor") -> KernelPlan:
     """PolyTOPS-planned matmul: tensor-style scheduling yields the
-    cache/VMEM-friendly (i, k, j) order with j vectorized (lanes)."""
+    cache/VMEM-friendly (i, k, j) order with j vectorized (lanes); the
+    tiles are then fitted to the kernel (:func:`_fit_matmul_plan`)."""
     remote = _remote_plan("matmul", m, n, k, strategy)
     if remote is not None:
         return remote
+    return _fit_matmul_plan(lower_matmul(m, n, k), m, n, k)
+
+
+def lower_matmul(m: int, n: int, k: int) -> KernelPlan:
+    """The general lowering of the matmul SCoP's schedule tree, with
+    :func:`_fit_tiles`'s tiles."""
     scop = _matmul_scop(m, n, k)
     cfg = tensor_style()
     cfg.auto_vectorize = True
@@ -308,6 +317,79 @@ def plan_matmul(m: int, n: int, k: int,
     sched = schedule_with_ladder(scop, cfg, cache=global_cache(),
                                  with_tree=True)
     return lower_to_kernel_plan(schedule_tree(sched), sched=sched)
+
+
+def _tile_choices(d: int, unit: int) -> List[int]:
+    """Tiles a dim of extent ``d`` may take: its divisors that are
+    multiples of ``unit``, and the whole dim."""
+    return [t for t in range(unit, d, unit) if d % t == 0] + [d]
+
+
+def matmul_block_bytes(tile: Dict[str, int], elem_bytes: int = 4) -> int:
+    """VMEM bytes the planned matmul kernel holds for a tile: its A, B
+    and C blocks double-buffered, plus the f32 accumulator.  Counted at
+    f32 operands by default, so a tile that fits holds for bf16 and f32
+    callers alike; that bound is tighter than :func:`_fit_tiles`'s
+    (3 × the bf16 footprint of the statement's accesses)."""
+    bm, bn, bk = tile["i"], tile["j"], tile["kk"]
+    return 2 * elem_bytes * (bm * bk + bk * bn + bm * bn) + 4 * bm * bn
+
+
+def matmul_tiles(m: int, n: int, k: int) -> List[Dict[str, int]]:
+    """The tiles the matmul fit chooses among: lane-snapped j and kk,
+    sublane-snapped i, each dividing its dim, whose blocks
+    (:func:`matmul_block_bytes`) fit VMEM."""
+    tiles = ({"i": bm, "j": bn, "kk": bk}
+             for bm in _tile_choices(m, SUBLANE)
+             for bn in _tile_choices(n, LANE)
+             for bk in _tile_choices(k, LANE))
+    return [t for t in tiles if matmul_block_bytes(t) <= VMEM_BYTES]
+
+
+def matmul_hbm_bytes(m: int, n: int, k: int, tile: Dict[str, int],
+                     elem_bytes: int = 2) -> int:
+    """HBM bytes the planned matmul kernel (:mod:`repro.kernels.
+    matmul_polytops`) moves at ``tile``, counted the way its BlockSpecs
+    fetch: Pallas skips a block's copy while its index is unchanged, so
+    the (bm, K) A panel stays resident when ``bk == K`` and is otherwise
+    read once per j tile; the weight is read once per i tile; C is
+    written once."""
+    bm, bn, bk = tile["i"], tile["j"], tile["kk"]
+    a = m * k * (1 if bk == k else n // bn)
+    return elem_bytes * (a + k * n * (m // bm) + m * n)
+
+
+def matmul_grid_steps(m: int, n: int, k: int, tile: Dict[str, int]) -> int:
+    return (m // tile["i"]) * (n // tile["j"]) * (k // tile["kk"])
+
+
+#: time one grid step of the planned matmul costs beyond its DMA and MXU
+#: work: 0.265 µs, the least-squares fit of ``python -m
+#: repro.kernels.bench --chip`` over 45 tiles at granite_3_2b's MLP
+#: shapes on a TPU v5e
+GRID_STEP_S = 0.265e-6
+
+
+def matmul_time_s(m: int, n: int, k: int, tile: Dict[str, int]) -> float:
+    """Estimated time of the planned matmul kernel at ``tile``: the
+    larger of its HBM bytes over bandwidth and its FLOPs over peak, plus
+    its grid steps times :data:`GRID_STEP_S`."""
+    return (max(matmul_hbm_bytes(m, n, k, tile) / HBM_BW,
+                2 * m * n * k / PEAK_FLOPS)
+            + matmul_grid_steps(m, n, k, tile) * GRID_STEP_S)
+
+
+def _fit_matmul_plan(plan: KernelPlan, m: int, n: int, k: int
+                     ) -> KernelPlan:
+    """Re-fit a lowered matmul plan's tiles to the one of
+    :func:`matmul_tiles` with the least :func:`matmul_time_s`.  The
+    schedule's loop order and lane dim stay; only the tiles change.
+    Where no tile fits the budget, the lowered tiles stand."""
+    tiles = matmul_tiles(m, n, k)
+    if not tiles:
+        return plan
+    return replace(plan, tile=min(
+        tiles, key=functools.partial(matmul_time_s, m, n, k)))
 
 
 @_plan_memo("attention")

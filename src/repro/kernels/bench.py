@@ -5,6 +5,9 @@ times are NOT TPU times — the CSV reports (a) interpret-mode sanity
 timings, (b) the PolyTOPS plan for each kernel (the actual deliverable:
 grid order/tiles), and (c) the XLA-reference timing for context.
 
+``python -m repro.kernels.bench --chip`` times the planned matmul on a
+TPU at granite_3_2b's MLP shapes (:func:`chip`); it exits 2 elsewhere.
+
 ``python -m repro.kernels.bench --smoke`` is the JAX-CPU smoke gate run
 by ``scripts/tier1.sh`` / CI: every kernel executes through the
 schedule-tree → ``lower_to_kernel_plan`` lowering (interpret mode) and
@@ -16,15 +19,18 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import akg
 from ..core.akg import (plan_attention, plan_matmul, plan_mamba_scan,
                         plan_scan_gate)
+from ..launch.roofline import HBM_BW, PEAK_FLOPS
 from ..model.ssm import discretise
-from . import ops, ref
+from . import matmul_polytops, ops, ref
 
 
 def _time(fn, *args, reps=3):
@@ -169,13 +175,92 @@ def smoke(out=sys.stdout) -> int:
     return failures
 
 
+#: granite_3_2b's MLP products of one 256-row prefill chunk: gate and up
+#: (rows x d_model . d_model x d_ff), then down, as (M, N, K)
+MLP_SHAPES = ((256, 8192, 2048), (256, 2048, 8192))
+#: distinct weights per timed program, as the layers of a model walk
+LAYERS = 16
+
+
+def _per_call_us(fn, a, ws, reps=10, rounds=3) -> float:
+    """Device-bound time of one ``fn(a, w)``, µs: one jitted program
+    runs it on each of ``ws`` in turn (distinct weights, so no call is
+    merged or hoisted); ``reps`` programs are queued before one wait, so
+    the host's dispatch overlaps the device's work; warm, best of
+    ``rounds``."""
+    f = jax.jit(lambda a, ws: [fn(a, w) for w in ws])
+    jax.block_until_ready(f(a, ws))
+    best = float("inf")
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        jax.block_until_ready([f(a, ws) for _ in range(reps)])
+        best = min(best, time.perf_counter() - t0)
+    return best / (reps * len(ws)) * 1e6
+
+
+def chip(out=sys.stdout) -> int:
+    """Time the planned matmul at :data:`MLP_SHAPES` on a TPU: at the
+    general lowering's tile, at the fitted tile, at every tile the fit
+    chooses among with at least half the rows and at most 64 grid steps,
+    and ``jnp.dot``; then fit the per-step cost that
+    ``akg.GRID_STEP_S`` holds (least squares of µs over HBM bytes and
+    grid steps).  Roofline share: the larger of the product's FLOPs over
+    peak and its operands' bytes over bandwidth, over the time."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"--chip needs a TPU, found {dev.platform}", file=sys.stderr)
+        return 2
+    print(f"device,{dev.device_kind},grid_step_s_now,{akg.GRID_STEP_S}",
+          file=out)
+    print("m,n,k,kernel,bm,bn,bk,grid_steps,hbm_mb,est_us,us,roofline_pct",
+          file=out)
+    fit_rows, fit_us = [], []
+    for m, n, k in MLP_SHAPES:
+        r = jax.random.PRNGKey(m + n + k)
+        a = jax.random.normal(r, (m, k), jnp.bfloat16)
+        ws = [jax.random.normal(jax.random.fold_in(r, i), (k, n),
+                                jnp.bfloat16) for i in range(LAYERS)]
+        least = max(2 * m * n * k / PEAK_FLOPS,
+                    2 * (m * k + k * n + m * n) / HBM_BW)
+        plan = plan_matmul(m, n, k)
+        lowered = akg.lower_matmul(m, n, k).tile
+        runs = [("lowered", lowered), ("fitted", plan.tile)] + [
+            ("sweep", t) for t in akg.matmul_tiles(m, n, k)
+            if 2 * t["i"] >= m and akg.matmul_grid_steps(m, n, k, t) <= 64
+            and t not in (lowered, plan.tile)]
+        for name, tile in runs:
+            p = replace(plan, tile=tile)
+            us = _per_call_us(lambda x, w, p=p: matmul_polytops.matmul(
+                x, w, plan=p, interpret=False), a, ws)
+            nb = akg.matmul_hbm_bytes(m, n, k, tile)
+            steps = akg.matmul_grid_steps(m, n, k, tile)
+            fit_rows.append([1.0, nb, steps])
+            fit_us.append(us)
+            print(f"{m},{n},{k},{name},{tile['i']},{tile['j']},"
+                  f"{tile['kk']},{steps},{nb / 1e6:.3f},"
+                  f"{akg.matmul_time_s(m, n, k, tile) * 1e6:.2f},"
+                  f"{us:.2f},{100 * least / (us * 1e-6):.2f}", file=out)
+        us = _per_call_us(jnp.dot, a, ws)
+        print(f"{m},{n},{k},jnp.dot,,,,,,,{us:.2f},"
+              f"{100 * least / (us * 1e-6):.2f}", file=out)
+    (c0, per_byte, per_step), *_ = np.linalg.lstsq(
+        np.array(fit_rows), np.array(fit_us), rcond=None)
+    print(f"fit,overhead_us,{c0:.3f},gb_per_s,{1e-3 / per_byte:.1f},"
+          f"grid_step_us,{per_step:.4f}", file=out)
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="run the numerical smoke gate instead of timings")
+    ap.add_argument("--chip", action="store_true",
+                    help="time the planned matmul's tiles on a TPU")
     args = ap.parse_args(argv)
     if args.smoke:
         return 1 if smoke() else 0
+    if args.chip:
+        return chip()
     run()
     return 0
 

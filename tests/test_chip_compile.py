@@ -66,6 +66,17 @@ def test_planned_matmul_compiles_at_granite_widths(spec, m, k, n):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_planned_matmul_compiles_at_its_largest_fitted_tiles(spec, dtype):
+    """gemma3_4b's MLP down projection over 2048 rows: the fitted tile
+    is among the largest the VMEM budget allows, and Mosaic's scoped
+    VMEM holds it for bf16 and f32 operands alike."""
+    m, k, n = 2048, 10240, 2560
+    text = _mosaic_text(lambda a, b: mm.matmul(a, b, interpret=False),
+                        spec((m, k), dtype), spec((k, n), dtype))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("seq", [256, 44])
 def test_scan_gate_compiles_at_falcon_mamba_widths(spec, seq):
     """Fused discretisation + scan + gate over a prefill chunk at d_inner

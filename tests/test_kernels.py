@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core.akg import plan_attention, plan_matmul
-from repro.kernels import ops, ref
+from repro.kernels import matmul_polytops, ops, ref
 
 
 @pytest.mark.parametrize("m,n,k", [(128, 128, 128), (256, 512, 128),
@@ -166,12 +166,45 @@ def _assert_tpu_legal(plan, scop, stmt_idx, dims, bytes_per_elem, n_buffers):
 
 @pytest.mark.parametrize("m,n,k", [(128, 128, 128), (256, 512, 128),
                                    (512, 512, 512), (64, 256, 512),
-                                   (1024, 1024, 512), (2048, 2048, 2048)])
+                                   (1024, 1024, 512), (2048, 2048, 2048),
+                                   (256, 8192, 2048), (256, 2048, 8192)])
 def test_matmul_plan_tpu_legal(m, n, k):
     from repro.core.akg import _matmul_scop
     plan = plan_matmul(m, n, k)
     _assert_tpu_legal(plan, _matmul_scop(m, n, k), 0,
                       {"i": m, "j": n, "kk": k}, 2, 3)
+
+
+@pytest.mark.parametrize("m,n,k", [(256, 8192, 2048), (256, 2048, 8192)])
+def test_matmul_plan_streams_each_weight_once(m, n, k):
+    """At granite_3_2b's MLP shapes (a 256-row prefill chunk: gate/up,
+    then down) the fitted plan keeps every row in one tile, so the
+    weight is read once, in few grid steps, and the HBM bytes its
+    BlockSpecs move stay near the operands' own."""
+    from repro.core.akg import matmul_grid_steps, matmul_hbm_bytes
+    tile = plan_matmul(m, n, k).tile
+    assert tile["i"] == 256
+    assert matmul_grid_steps(m, n, k, tile) <= 32
+    assert matmul_hbm_bytes(m, n, k, tile) <= 1.1 * 2 * (m * k + k * n
+                                                        + m * n)
+
+
+@pytest.mark.parametrize("m,n,k,k_steps", [(64, 256, 512, 1),
+                                           (64, 256, 16384, 4)])
+def test_matmul_matches_oracle_at_fitted_plan(m, n, k, k_steps):
+    """The kernel at the fitted plan: one k step with the A panel
+    resident, and the whole N in one lane tile with the f32 accumulator
+    carried over several k steps."""
+    plan = plan_matmul(m, n, k)
+    assert k // plan.tile["kk"] == k_steps
+    assert k_steps == 1 or plan.tile["j"] == n
+    r = jax.random.PRNGKey(3)
+    a = jax.random.normal(r, (m, k), jnp.float32)
+    b = jax.random.normal(jax.random.fold_in(r, 1), (k, n), jnp.float32)
+    got = matmul_polytops.matmul(a, b, plan=plan, interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.matmul_ref(a, b)),
+                               rtol=1e-4, atol=1e-4 * k ** 0.5)
 
 
 @pytest.mark.parametrize("sq,sk,d", [(128, 128, 64), (512, 512, 128),
@@ -265,6 +298,24 @@ def test_scan_block_bytes_mirrors_the_kernels_blockspecs(chunk, d_block):
     assert scan_block_bytes(tile, fused=False) == plain
 
 
+@pytest.mark.parametrize("tile", [{"i": 256, "j": 512, "kk": 2048},
+                                  {"i": 256, "j": 2048, "kk": 512},
+                                  {"i": 64, "j": 128, "kk": 256}])
+def test_matmul_block_bytes_mirrors_the_kernels_blockspecs(tile):
+    """The matmul fit's VMEM footprint is the kernel's own: A, B and C
+    blocks double-buffered, counted as f32, plus the f32 accumulator."""
+    from dataclasses import replace
+    from repro.core.akg import matmul_block_bytes
+    m, n, k = 2 * tile["i"], 2 * tile["j"], 2 * tile["kk"]
+    plan = replace(plan_matmul(m, n, k), tile=tile)
+    got = _pallas_vmem_bytes(
+        lambda a, b: matmul_polytops.matmul(a, b, plan=plan,
+                                            interpret=True),
+        jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
+    assert matmul_block_bytes(tile) == got
+
+
 def test_mamba_kernel_consumes_scheduler_plan():
     """selective_scan's default block geometry comes from the schedule
     tree (no hand-coded order/tiles) and still matches the oracle."""
@@ -287,14 +338,20 @@ def test_mamba_kernel_consumes_scheduler_plan():
 
 
 def test_plan_wrappers_are_thin_over_general_lowering():
-    """plan_matmul is the general tree lowering, nothing more."""
-    from repro.core.akg import _matmul_scop
+    """plan_matmul is the general tree lowering followed by the matmul
+    tile fit, nothing more: the schedule's loop order and lanes stay."""
+    from repro.core.akg import _fit_matmul_plan, _matmul_scop
     from repro.core.config import tensor_style
     from repro.core.schedcache import cached_schedule_scop
     from repro.core.schedtree import schedule_tree
 
-    scop = _matmul_scop(256, 256, 256)
+    m, n, k = 256, 8192, 2048
+    scop = _matmul_scop(m, n, k)
     cfg = tensor_style()
     cfg.auto_vectorize = True
     sched = cached_schedule_scop(scop, cfg)
-    assert lower_to_kernel_plan(schedule_tree(sched)) == plan_matmul(256, 256, 256)
+    lowered = lower_to_kernel_plan(schedule_tree(sched))
+    plan = plan_matmul(m, n, k)
+    assert _fit_matmul_plan(lowered, m, n, k) == plan
+    assert (plan.loop_order, plan.vector_iter) == (lowered.loop_order,
+                                                   lowered.vector_iter)
